@@ -17,6 +17,9 @@ def main() -> None:
                          "fig4,fig5,fig6,kernel,roofline")
     args = ap.parse_args()
 
+    from repro.api import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (capacity_bench, common, copy_cost,
                             engine_bench, fig3_chunks,
                             fig4_multidevice, fig5_scaling, fig6_outliers,

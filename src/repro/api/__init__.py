@@ -69,7 +69,7 @@ from repro.api.spec import (
     SearchStats,
     StatResult,
 )
-from repro.api.index import KNNIndex
+from repro.api.index import KNNIndex, enable_compile_cache
 
 # Register the built-in engines (import side effect populates the registry).
 from repro.api import engines as _engines  # noqa: F401
@@ -85,6 +85,7 @@ from repro.core.dualtree import dualtree_cache_size
 
 __all__ = [
     "KNNIndex",
+    "enable_compile_cache",
     "IndexSpec",
     "QueryResult",
     "RadiusResult",

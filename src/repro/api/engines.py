@@ -515,7 +515,8 @@ class ForestEngine(EngineBase):
     AXIS = "knn"
 
     def build(self, points, spec, plan):
-        import jax.numpy as jnp
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec
 
         from repro.distributed.forest import build_forest, stack_forest
 
@@ -529,10 +530,17 @@ class ForestEngine(EngineBase):
                 "uneven sets"
             )
         trees, offsets = build_forest(points, ns, height=plan.height)
+        mesh = _mesh_over(spec.devices, ns, self.AXIS)
+        # each device holds its own shard's tree from build on, so a query
+        # moves only the queries and the [m, k] candidate lists
+        shard = NamedSharding(mesh, PartitionSpec(self.AXIS))
+        stacked, offsets = jax.device_put(
+            (stack_forest(trees), offsets), shard
+        )
         return _ForestState(
-            stacked=stack_forest(trees),
-            offsets=jnp.asarray(offsets),
-            mesh=_mesh_over(spec.devices, ns, self.AXIS),
+            stacked=stacked,
+            offsets=offsets,
+            mesh=mesh,
             first_leaf_heap=1 << plan.height,
             d=points.shape[1],
             d_pad=int(trees[0].slabs.shape[-1]),

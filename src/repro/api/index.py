@@ -41,7 +41,7 @@ from repro.api.spec import (
 )
 from repro.persist import PersistError, VersionStore, WriteAheadLog
 
-__all__ = ["KNNIndex"]
+__all__ = ["KNNIndex", "compile_cache_dir", "enable_compile_cache"]
 
 # IndexSpec fields recorded in a snapshot manifest (JSON-able, topology-
 # free): device handles and measured calibrations belong to the HOST that
@@ -54,6 +54,14 @@ _SPEC_MANIFEST_FIELDS = (
 )
 
 
+# The fixed in-checkout cache directory the entry points use when
+# JAX_COMPILATION_CACHE_DIR is not set: a cache is keyed by its path, so a
+# temporary or per-process directory would never be hit again.
+DEFAULT_COMPILE_CACHE_DIR = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "..", "..", ".jax_cache")
+)
+
+
 def _compile_cache_entries(path: str) -> int:
     """Serialized executables currently in a persistent compile cache dir."""
     try:
@@ -62,9 +70,21 @@ def _compile_cache_entries(path: str) -> int:
         return 0
 
 
-def _enable_compile_cache(path: str) -> str:
-    """Point jax's persistent compilation cache at ``path`` and return the
-    auditable reason string (entry count decides warm vs cold).
+def compile_cache_dir(path: Optional[str] = None) -> str:
+    """Where the persistent compilation cache lives: the directory
+    ``JAX_COMPILATION_CACHE_DIR`` names when it is set (jax reads it at
+    import, and nothing here overrides it), else ``path``, else
+    ``DEFAULT_COMPILE_CACHE_DIR``."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    return os.path.abspath(env or path or DEFAULT_COMPILE_CACHE_DIR)
+
+
+def enable_compile_cache(path: Optional[str] = None) -> str:
+    """Turn jax's persistent compilation cache on at
+    ``compile_cache_dir(path)`` and return the auditable reason string
+    (entry count decides warm vs cold).  Entry points (CLI, benchmarks, the
+    smoke script) call it with no path before their first compile; an index
+    calls it with ``IndexSpec.compile_cache_dir``.
 
     The threshold knobs are zeroed because this repo's executables are
     many SMALL kernels (fused rounds, ladder gathers, scan tiles) — the
@@ -75,14 +95,17 @@ def _enable_compile_cache(path: str) -> str:
     """
     import jax
 
-    path = os.path.abspath(path)
+    from_env = bool(os.environ.get("JAX_COMPILATION_CACHE_DIR"))
+    path = compile_cache_dir(path)
     os.makedirs(path, exist_ok=True)
     n = _compile_cache_entries(path)
-    jax.config.update("jax_compilation_cache_dir", path)
+    if not from_env:
+        jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    src = " (JAX_COMPILATION_CACHE_DIR)" if from_env else ""
     return (
-        f"compile cache at {path}: {n} executable(s) on disk "
+        f"compile cache at {path}{src}: {n} executable(s) on disk "
         f"({'warm' if n else 'cold'} start)"
     )
 
@@ -170,7 +193,7 @@ class KNNIndex:
             # enable BEFORE the engine builds: build-phase compiles (warm-
             # at-build precompilation, initial scans) populate the cache
             pl = pl.replace(reasons=pl.reasons + (
-                _enable_compile_cache(spec.compile_cache_dir),
+                enable_compile_cache(spec.compile_cache_dir),
             ))
         engine = get_engine(pl.engine)
         state = engine.build(points, spec, pl)
@@ -318,7 +341,7 @@ class KNNIndex:
         )
         if spec.compile_cache_dir:
             pl = pl.replace(reasons=pl.reasons + (
-                _enable_compile_cache(spec.compile_cache_dir),
+                enable_compile_cache(spec.compile_cache_dir),
             ))
         engine = get_engine(pl.engine)
         state = engine.restore_state(
@@ -639,7 +662,10 @@ class KNNIndex:
                 )
         k = int(k) if k is not None else self.spec.k_hint
         mm = int(m) if m is not None else (self.spec.m_hint or self.spec.tile_q)
-        ccd = self.spec.compile_cache_dir
+        ccd = (
+            compile_cache_dir(self.spec.compile_cache_dir)
+            if self.spec.compile_cache_dir else None
+        )
         before = _compile_cache_entries(ccd) if ccd else 0
         if "knn" in ops:
             warm = getattr(self._state, "warm", None)
@@ -677,6 +703,13 @@ class KNNIndex:
         return self.plan.height
 
     @property
+    def scan_backend(self) -> Optional[str]:
+        """The leaf-scan kernel backend the built engine runs ("pallas",
+        "pallas_interpret" or "ref"), resolved from ``spec.backend`` and the
+        platform; None for engines without a leaf-scan tier."""
+        return getattr(self._state, "scan_backend", None)
+
+    @property
     def stats(self) -> SearchStats:
         """Stats of the most recent ``query`` (immutable; empty before).
 
@@ -697,7 +730,8 @@ class KNNIndex:
         lines = [
             f"KNNIndex: n={self.n} d={self.d} engine={pl.engine} "
             f"h={pl.height} n_chunks={pl.n_chunks} n_shards={pl.n_shards} "
-            f"B={pl.buffer_size} resident~{pl.resident_bytes / 1e6:.1f}MB",
+            f"B={pl.buffer_size} resident~{pl.resident_bytes / 1e6:.1f}MB"
+            + (f" scan={self.scan_backend}" if self.scan_backend else ""),
         ]
         lines += [f"  - {r}" for r in pl.reasons]
         return "\n".join(lines)

@@ -53,7 +53,7 @@ from typing import Any, Mapping, Optional, Sequence, Tuple
 
 from repro.core.chunked_jit import DEFAULT_STARVATION_DEADLINE
 from repro.core.quantize import BYTES_PER_ELEM, PRECISIONS
-from repro.core.toptree import default_buffer_size, suggest_height
+from repro.core.toptree import default_buffer_size, slab_len, suggest_height
 
 __all__ = [
     "Plan",
@@ -103,9 +103,7 @@ def _pad_dims(
     n: int, d: int, height: int, leaf_pad_multiple: int, d_pad_multiple: int
 ) -> Tuple[int, int, int]:
     n_leaves = 1 << height
-    leaf_pad = max(
-        _round_up(-(-n // n_leaves), leaf_pad_multiple), leaf_pad_multiple
-    )
+    leaf_pad = slab_len(-(-n // n_leaves), leaf_pad_multiple)
     d_pad = max(_round_up(d, d_pad_multiple), d_pad_multiple)
     return n_leaves, leaf_pad, d_pad
 
@@ -117,8 +115,8 @@ def estimate_slab_bytes(
     """Device bytes of the padded leaf structure at tree height ``height``.
 
     Mirrors ``build_top_tree``'s padding: 2**h equal (±1) leaves of
-    ceil(n / 2**h) points, slab length rounded up to ``leaf_pad_multiple``,
-    feature dim rounded up to ``d_pad_multiple``.  ``precision`` scales the
+    ceil(n / 2**h) points, slab length from ``toptree.slab_len``, feature
+    dim rounded up to ``d_pad_multiple``.  ``precision`` scales the
     per-element cost (fp32 4B, fp16 2B, int8 1B — ``core.quantize``).
     """
     n_leaves, leaf_pad, d_pad = _pad_dims(

@@ -1,10 +1,7 @@
-"""JAX version compatibility shims.
+"""Thin wrappers over the sharding API the repo uses.
 
-The repo targets the modern sharding API (``jax.shard_map``,
-``jax.sharding.AxisType``), but the pinned container runs jax 0.4.x where
-those names live elsewhere (or do not exist).  Every module that builds a
-mesh or wraps a shard_map body goes through these two helpers so the same
-code runs on both lines.
+Every module that builds a mesh or wraps a shard_map body goes through these
+helpers, so the axis-type and replication-check choices live in one place.
 """
 
 from __future__ import annotations
@@ -12,37 +9,16 @@ from __future__ import annotations
 from typing import Any, Callable, Sequence
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_mesh", "shard_map", "axis_size", "cost_analysis"]
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict (0.4.x wraps it in a
-    one-element list per device)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
-def axis_size(axis: str) -> int:
-    """``jax.lax.axis_size`` (new) or the psum-of-one idiom (0.4.x), both of
-    which produce a static size usable in Python control flow."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)
+__all__ = ["make_mesh", "shard_map"]
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> "jax.sharding.Mesh":
-    """``jax.make_mesh`` with Auto axis types when the API supports them."""
-    try:
-        from jax.sharding import AxisType
-
-        return jax.make_mesh(
-            tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes)
-        )
-    except ImportError:
-        return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def shard_map(
@@ -53,17 +29,8 @@ def shard_map(
     out_specs: Any,
     check: bool = False,
 ) -> Callable[..., Any]:
-    """``jax.shard_map`` (new) or ``jax.experimental.shard_map`` (0.4.x).
-
-    ``check`` maps to ``check_vma`` on the new API and ``check_rep`` on the
-    old one (both default False here: the kNN bodies do manual collectives).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
+    """``jax.shard_map``; ``check`` maps to ``check_vma`` (default False:
+    the kNN bodies do manual collectives)."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
     )

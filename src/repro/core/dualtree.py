@@ -163,11 +163,19 @@ def _pairwise_d2(A: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
     """Squared distances [P, a, b] via the |a|^2 + |b|^2 - 2ab expansion
     (no [P, a, b, d] intermediate).  PAD_COORD rows against real rows come
     out huge (~1e36, excluded by any real radius/edge); PAD against PAD
-    cancels to garbage near 0 — callers mask or row-slice those."""
+    cancels to garbage near 0 — callers mask or row-slice those.
+
+    The cross term runs at HIGHEST precision (a default f32 dot may be one
+    bf16 pass on a TPU) and the sum is ordered (|a|^2 - 2ab) + |b|^2: for
+    non-negative coordinates every partial sum is bounded by
+    max(|a|^2, |b|^2), so integer coordinates whose squared norms fit in
+    f32's 24-bit mantissa give exact squared distances."""
     a2 = jnp.sum(A * A, axis=-1)
     b2 = jnp.sum(B * B, axis=-1)
-    cross = jnp.einsum("pad,pbd->pab", A, B)
-    return jnp.maximum(a2[:, :, None] + b2[:, None, :] - 2.0 * cross, 0.0)
+    cross = jnp.einsum(
+        "pad,pbd->pab", A, B, precision=jax.lax.Precision.HIGHEST
+    )
+    return jnp.maximum(a2[:, :, None] - 2.0 * cross + b2[:, None, :], 0.0)
 
 
 @jax.jit
@@ -833,9 +841,10 @@ def radius_brute(
     rows, cols, dists = [], [], []
     for lo in range(0, m, tile_q):
         q = queries[lo:lo + tile_q]
+        # summed in _pairwise_d2's order, so fp32 rounding matches it
         d2 = (
-            (q * q).sum(1)[:, None] + (points * points).sum(1)[None, :]
-            - 2.0 * (q @ points.T)
+            (q * q).sum(1)[:, None] - 2.0 * (q @ points.T)
+            + (points * points).sum(1)[None, :]
         ).astype(np.float32)
         np.maximum(d2, 0.0, out=d2)
         qi, rj = np.nonzero(d2 <= r2)
@@ -889,8 +898,9 @@ def _brute_hist_tile(q, points, edges):
     dual-tree speedup is measured against an honest baseline)."""
     E = edges.shape[0] - 1
     d2 = jnp.maximum(
-        (q * q).sum(1)[:, None] + (points * points).sum(1)[None, :]
-        - 2.0 * (q @ points.T),
+        (q * q).sum(1)[:, None]
+        - 2.0 * jnp.matmul(q, points.T, precision=jax.lax.Precision.HIGHEST)
+        + (points * points).sum(1)[None, :],
         0.0,
     )
     dist = jnp.sqrt(d2).reshape(-1)

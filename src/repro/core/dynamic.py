@@ -117,6 +117,7 @@ from repro.core.quantize import BYTES_PER_ELEM, PRECISIONS
 from repro.core.toptree import (
     PAD_COORD,
     _round_up,
+    slab_len,
     suggest_height,
     tree_from_arrays,
     tree_to_arrays,
@@ -688,7 +689,7 @@ class DynamicIndex:
         rung-``cap`` tree shard at ``height`` — the planner's residency
         model (same padding rules as ``build_top_tree``)."""
         n_leaves = 1 << height
-        leaf_pad = max(_round_up(-(-cap // n_leaves), 8), 8)
+        leaf_pad = slab_len(-(-cap // n_leaves))
         d_pad = max(_round_up(self.d, 8), 8)
         leaf_bytes = leaf_pad * d_pad * BYTES_PER_ELEM[self.precision]
         if self.precision == "fp32":

@@ -349,6 +349,7 @@ class BufferKDTree:
         self._last_stats = SearchStats()
 
         resolved = kops.default_backend() if backend == "auto" else backend
+        self.scan_backend = resolved   # the leaf-scan kernel that runs
         self.engine_tile_q = int(
             engine_tile_q
             if engine_tile_q is not None

@@ -25,10 +25,13 @@ from typing import Optional
 
 import numpy as np
 
+from repro.kernels.knn_scan import DEFAULT_TX
+
 __all__ = [
     "TopTree",
     "build_top_tree",
     "default_buffer_size",
+    "slab_len",
     "suggest_height",
     "tree_to_arrays",
     "tree_from_arrays",
@@ -81,6 +84,21 @@ def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
 
 
+def slab_len(max_leaf: int, multiple: int = 8) -> int:
+    """Padded slab length for leaves of at most ``max_leaf`` points.
+
+    The one place the slab length is decided (tree build, planner byte
+    estimates and the dynamic forest's residency all call it).  Slabs are
+    padded to a multiple of ``multiple``; a slab longer than one leaf-scan
+    kernel tile (``DEFAULT_TX``) is padded to a whole number of tiles, so the
+    kernel's slab-tile grid covers it exactly for any n.
+    """
+    lp = max(_round_up(max_leaf, multiple), multiple)
+    if lp > DEFAULT_TX:
+        lp = _round_up(lp, int(np.lcm(DEFAULT_TX, multiple)))
+    return lp
+
+
 # Padding coordinate for slab rows holding no real point.  Large but FINITE:
 # the kernel's ||q||^2 - 2 q.x + ||x||^2 decomposition would produce NaN from
 # inf * 0; 1e18 keeps ||x||^2 ~ 1e36 < f32 max while dominating any real
@@ -103,7 +121,7 @@ def build_top_tree(
       height: tree height h; produces 2**h leaves.  Must satisfy
         ``2**h <= n`` so every leaf is non-empty.
       leaf_pad_multiple: pad the per-leaf slab view up to a multiple of this
-        (sub-lane friendly; kernels later pad to their own tiles anyway).
+        (sub-lane friendly); ``slab_len`` adds the kernel-tile rule.
       dim_rule: "cyclic" (level mod d, the paper's original rule) or
         "widest" (split the dimension of largest spread, footnote 2).
     """
@@ -172,7 +190,7 @@ def build_top_tree(
     orig_idx = order.astype(np.int32)
 
     max_leaf = int((leaf_end - leaf_start).max())
-    leaf_pad = max(_round_up(max_leaf, leaf_pad_multiple), leaf_pad_multiple)
+    leaf_pad = slab_len(max_leaf, leaf_pad_multiple)
     padded = np.full((n_leaves, leaf_pad, d), np.float32(pad_value), dtype=np.float32)
     for leaf in range(n_leaves):
         s, e = leaf_start[leaf], leaf_end[leaf]

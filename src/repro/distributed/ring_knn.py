@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
+from repro.compat import shard_map
 from repro.kernels.ref import INVALID_DIST
 
 __all__ = ["ring_knn_brute", "ring_knn_shardmap_fn"]
@@ -87,7 +87,7 @@ def ring_knn_shardmap_fn(k: int, axis: str, pad_coord_guard: bool = True):
     """
 
     def body(q_local: jnp.ndarray, refs_local: jnp.ndarray):
-        p = axis_size(axis)
+        p = jax.lax.axis_size(axis)
         me = jax.lax.axis_index(axis)
         nb = refs_local.shape[0]
         mb = q_local.shape[0]

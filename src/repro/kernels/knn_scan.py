@@ -34,8 +34,14 @@ k-selection comes in two forms (``selection=``):
     reductions + masking, the most conservative lowering.
 
 Both forms move values around without re-deriving them and break ties toward
-the lower slab index, so they are bit-identical to each other and to
-``kernels/ref.py::leaf_scan_ref`` (``lax.top_k`` tie order).
+the lower slab index (``lax.top_k`` order), so they are bit-identical to each
+other.  Against ``kernels/ref.py::leaf_scan_ref``, an XLA lowering of the same
+arithmetic, the contract is a tolerance: distances agree within
+``rtol = atol = 1e-5`` (two lowerings of one matmul may round differently,
+and by more on a chip than in interpret mode), and the selected neighbours
+agree as sets up to ties within that tolerance.  The cross term runs at
+``Precision.HIGHEST``: a default-precision f32 dot may run as one bf16 pass
+on a TPU, an error far larger than the gap between neighbours.
 
 Work-unit contract (shared with kernels/ref.py::leaf_scan_ref):
   q         f32[W, TQ, d_pad]   padded query tiles (pad rows = 0.0)
@@ -62,11 +68,6 @@ _BIG_I = 2**30  # python int: avoids captured-constant arrays in the kernel
 
 SELECTIONS = ("auto", "two_phase", "min_trick")
 
-# jax 0.4.x names the params class TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
 
 def _dist_tile(q, x):
     """[TQ, d] x [TX, d] -> [TQ, TX] squared distances (MXU decomposition)."""
@@ -74,6 +75,7 @@ def _dist_tile(q, x):
     xn = jnp.sum(x * x, axis=-1)[None, :]                          # [1, TX]
     cross = jax.lax.dot_general(
         q, x, dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )                                                              # [TQ, TX]
     return jnp.maximum(qn - 2.0 * cross + xn, 0.0)
@@ -226,7 +228,7 @@ def leaf_scan_pallas(
             pltpu.VMEM((tq, k), jnp.float32),
             pltpu.VMEM((tq, k), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

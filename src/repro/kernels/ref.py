@@ -35,6 +35,7 @@ def _decomposed_sq_dists(q: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     cross = jax.lax.dot_general(
         q, x,
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
     return jnp.maximum(qn - 2.0 * cross + xn, 0.0)

@@ -28,7 +28,7 @@ import time
 
 import numpy as np
 
-from repro.api import IndexSpec, KNNIndex, knn_brute
+from repro.api import IndexSpec, KNNIndex, enable_compile_cache, knn_brute
 from repro.data.pipeline import PointCloud
 
 
@@ -57,6 +57,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    print(f"[knn] {enable_compile_cache()}")
     pc = PointCloud(args.n, args.d, seed=args.seed)
     pts = pc.points()
     q = pc.queries(args.m)

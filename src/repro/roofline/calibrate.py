@@ -24,7 +24,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Dict, Optional
 
-from repro.compat import cost_analysis
 from repro.roofline.analysis import collective_bytes
 
 __all__ = ["CellCosts", "calibrated_costs"]
@@ -49,7 +48,7 @@ class CellCosts:
 
 
 def _costs_of(compiled) -> Dict[str, float]:
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     return {
         "flops": float(ca.get("flops", 0.0)),
         "bytes": float(ca.get("bytes accessed", 0.0)),

@@ -16,7 +16,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
 
 __all__ = ["ef_int8_allreduce", "init_error_state"]
 
@@ -46,7 +45,7 @@ def ef_int8_allreduce(grads, error_state, axis_name: str) -> Tuple[Any, Any]:
 
     Returns (mean_grads, new_error_state).
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     flat_g, tdef = jax.tree.flatten(grads)
     flat_e = jax.tree.leaves(error_state)
     means, errs = [], []

@@ -1,6 +1,6 @@
 """Optional-hypothesis shim for test modules that mix fuzz and plain tests.
 
-``from hypothesis_compat import given, settings, st`` behaves exactly like the
+``from hypothesis_compat import example, given, settings, st`` behaves exactly like the
 real hypothesis imports when the package is installed.  When it is not, the
 ``@given`` decorator turns the fuzz test into a skip (with a clear reason)
 while the rest of the module keeps collecting and running — the environment
@@ -10,7 +10,7 @@ does not ship hypothesis, and tier-1 collection must not depend on it.
 from __future__ import annotations
 
 try:
-    from hypothesis import given, settings, strategies as st  # noqa: F401
+    from hypothesis import example, given, settings, strategies as st  # noqa: F401
 
     HAVE_HYPOTHESIS = True
 except ModuleNotFoundError:  # pragma: no cover - exercised when hyp missing
@@ -29,6 +29,8 @@ except ModuleNotFoundError:  # pragma: no cover - exercised when hyp missing
             return fn
 
         return deco
+
+    example = settings
 
     class _AnyStrategy:
         """Stands in for ``strategies``: strategy constructors are evaluated at

@@ -52,6 +52,7 @@ _LIFECYCLE = textwrap.dedent("""
 def _lifecycle_run(cache_dir: str) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)   # the spec's dir applies
     out = subprocess.run(
         [sys.executable, "-c", _LIFECYCLE, cache_dir],
         capture_output=True, text=True, env=env, timeout=1800,
@@ -95,3 +96,55 @@ def test_spec_field_survives_replace_but_not_manifest():
     # (cache dirs belong to the saving host, like persist_dir)
     from repro.api.index import _SPEC_MANIFEST_FIELDS
     assert "compile_cache_dir" not in _SPEC_MANIFEST_FIELDS
+
+
+def test_cache_dir_resolution(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins wherever it is set; otherwise a spec's
+    directory, else the one fixed in-checkout path (never a temporary,
+    per-process or timestamped one)."""
+    from repro.api.index import DEFAULT_COMPILE_CACHE_DIR, compile_cache_dir
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = os.path.abspath(os.path.join(SRC, ".."))
+    assert DEFAULT_COMPILE_CACHE_DIR == os.path.join(root, ".jax_cache")
+    assert compile_cache_dir() == DEFAULT_COMPILE_CACHE_DIR
+    assert compile_cache_dir(str(tmp_path)) == str(tmp_path)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    assert compile_cache_dir() == str(tmp_path / "env")
+    assert compile_cache_dir(str(tmp_path)) == str(tmp_path / "env")
+
+
+# An entry point's cache under JAX_COMPILATION_CACHE_DIR: nothing overrides it
+_ENV_DIR = textwrap.dedent("""
+    import glob, os, sys
+    import numpy as np
+    import jax
+    from repro.api import IndexSpec, KNNIndex, enable_compile_cache
+
+    print(enable_compile_cache())
+    pts = np.random.default_rng(0).normal(size=(3000, 8)).astype(np.float32)
+    idx = KNNIndex.build(pts, spec=IndexSpec(
+        engine="chunked", height=3, compile_cache_dir=sys.argv[1]))
+    idx.query(pts[:64], k=5)
+    print("CONFIG", jax.config.jax_compilation_cache_dir)
+    print("ENTRIES", len(glob.glob(os.path.join(
+        os.environ["JAX_COMPILATION_CACHE_DIR"], "*-cache"))))
+    print("SPEC_DIR", os.path.exists(sys.argv[1]))
+""")
+
+
+def test_env_cache_dir_is_left_in_place(tmp_path):
+    env = dict(os.environ)
+    env["PYTHONPATH"] = SRC
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "env-cache")
+    out = subprocess.run(
+        [sys.executable, "-c", _ENV_DIR, str(tmp_path / "spec-cache")],
+        capture_output=True, text=True, env=env, timeout=1800,
+    )
+    assert out.returncode == 0, f"subprocess failed:\n{out.stderr[-3000:]}"
+    lines = out.stdout.splitlines()
+    assert f"compile cache at {tmp_path / 'env-cache'} " in lines[0]
+    assert "(JAX_COMPILATION_CACHE_DIR)" in lines[0]
+    assert f"CONFIG {tmp_path / 'env-cache'}" in lines
+    assert "SPEC_DIR False" in lines
+    assert int(next(x for x in lines if x.startswith("ENTRIES")).split()[1]) > 0

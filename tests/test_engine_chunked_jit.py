@@ -167,3 +167,22 @@ class TestUniformStore:
         for j in range(4):
             lo, hi = store.chunk_leaf_range(j)
             assert (ids[lo:hi] == j).all()
+
+
+class TestSlabTiling:
+    """Leaves longer than one kernel slab tile (512) whose length is not a
+    multiple of it: the slab is padded to whole tiles, so the Pallas scan
+    runs the engine path for any n (before, the kernel raised
+    ``L_pad=... not a multiple of tx=512``)."""
+
+    @pytest.mark.parametrize("n_chunks", [1, 2])
+    def test_engine_path_interpret_leaf_above_tile(self, n_chunks):
+        pts, q = _data(2400, 40, 5, seed=31)     # 4 leaves of 600 points
+        tree = BufferKDTree(pts, height=2, n_chunks=n_chunks,
+                            backend="pallas_interpret")
+        d, i = tree.query(q, k=7)
+        bd, bi = knn_host_kdtree(q, build_top_tree(pts, 2), 7)
+        np.testing.assert_allclose(d, bd, rtol=1e-5, atol=1e-5)
+        np.testing.assert_array_equal(i, bi)
+        assert tree.tree.leaf_sizes().max() == 600
+        assert tree.store.host.shape[1] == 1024

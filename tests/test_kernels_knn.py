@@ -1,16 +1,18 @@
 """Pallas leaf-scan kernel vs pure-jnp oracle: shape/dtype sweeps + fuzz.
 
 The kernel runs in interpret mode on CPU (the TPU lowering path is the
-target; interpret executes the same kernel body).  Selection is a
-discrete-boundary problem, so index agreement is checked permutation-aware
-(distances must match exactly; ties may reorder).
+target; interpret executes the same kernel body).  The kernel's contract
+against the oracle is a tolerance (``knn_scan.py``): distances within
+``rtol = atol = 1e-5``, and the selected neighbours checked regardless of
+order (each a distinct slab row whose own distance is the oracle's at that
+rank), since two lowerings of one matmul may round differently.
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis_compat import given, settings, st
+from hypothesis_compat import example, given, settings, st
 
 from repro.kernels.knn_scan import leaf_scan_pallas
 from repro.kernels.ops import leaf_scan
@@ -28,21 +30,26 @@ def _inputs(w, tq, lp, d, d_pad, seed=0, pad_rows=0):
     return jnp.asarray(q), jnp.asarray(x)
 
 
+# the kernel's stated contract against leaf_scan_ref (knn_scan.py)
+RTOL = ATOL = 1e-5
+
+
 def _check(q, x, k, tq=None, tx=None, selection="auto"):
-    rd, ri = leaf_scan_ref(q, x, k=k)
+    rd, _ri = leaf_scan_ref(q, x, k=k)
     pd_, pi = leaf_scan_pallas(q, x, k=k, interpret=True, selection=selection,
                                **({"tq": tq} if tq else {}),
                                **({"tx": tx} if tx else {}))
-    # selection only moves values, never re-derives them: the distances the
-    # kernel reports must be BIT-identical to the oracle's
-    np.testing.assert_array_equal(np.asarray(rd), np.asarray(pd_))
-    # permutation-aware index check: same distance at every rank
-    d_of_pi = np.take_along_axis(
-        np.asarray(_all_dists(q, x)), np.asarray(pi), axis=-1
-    )
-    np.testing.assert_allclose(d_of_pi, np.asarray(rd), rtol=1e-5, atol=1e-5)
+    rd, pd_, pi = np.asarray(rd), np.asarray(pd_), np.asarray(pi)
+    np.testing.assert_allclose(pd_, rd, rtol=RTOL, atol=ATOL)
+    # neighbours regardless of order: distinct valid slab rows, each at the
+    # oracle's distance for its rank
+    assert ((pi >= 0) & (pi < x.shape[1])).all()
+    srt = np.sort(pi, axis=-1)
+    assert (srt[..., 1:] != srt[..., :-1]).all()
+    d_of_pi = np.take_along_axis(np.asarray(_all_dists(q, x)), pi, axis=-1)
+    np.testing.assert_allclose(d_of_pi, rd, rtol=RTOL, atol=ATOL)
     # ascending order
-    assert (np.diff(np.asarray(pd_), axis=-1) >= -1e-6).all()
+    assert (np.diff(pd_, axis=-1) >= -1e-6).all()
 
 
 def _all_dists(q, x):
@@ -147,6 +154,9 @@ def test_brute_oracle_self_consistency():
     seed=st.integers(0, 500),
 )
 @settings(max_examples=10)
+# hypothesis's shrunk counterexample to the old bit-identity contract: the
+# kernel and the oracle differ there by one ulp (2.4e-7)
+@example(w=1, tq=8, lp_mult=2, d=2, k=1, seed=0)
 def test_kernel_fuzz(w, tq, lp_mult, d, k, seed):
     tx = 32
     lp = tx * lp_mult

@@ -9,7 +9,6 @@ import pytest
 from repro.roofline.analysis import (
     HW, collective_bytes, dominant_term, parse_shape_bytes, roofline_terms,
 )
-from repro.compat import cost_analysis
 from repro.roofline.calibrate import calibrated_costs
 from repro.roofline.model_flops import model_flops, param_counts
 
@@ -63,7 +62,7 @@ class TestCostAnalysisSemantics:
         c = jax.jit(lambda a, b: a @ b).lower(
             jax.ShapeDtypeStruct((m, k), jnp.float32),
             jax.ShapeDtypeStruct((k, n), jnp.float32)).compile()
-        assert cost_analysis(c)["flops"] == 2 * m * n * k
+        assert c.cost_analysis()["flops"] == 2 * m * n * k
 
     def test_scan_body_counted_once(self):
         def scanned(a, bs):
@@ -73,12 +72,12 @@ class TestCostAnalysisSemantics:
             return c
 
         a = jax.ShapeDtypeStruct((64, 64), jnp.float32)
-        f1 = cost_analysis(jax.jit(scanned).lower(
+        f1 = jax.jit(scanned).lower(
             a, jax.ShapeDtypeStruct((1, 64, 64), jnp.float32)
-        ).compile())["flops"]
-        f8 = cost_analysis(jax.jit(scanned).lower(
+        ).compile().cost_analysis()["flops"]
+        f8 = jax.jit(scanned).lower(
             a, jax.ShapeDtypeStruct((8, 64, 64), jnp.float32)
-        ).compile())["flops"]
+        ).compile().cost_analysis()["flops"]
         # THE quirk calibration exists for: the matmul body is counted once
         # regardless of trip count (tiny loop-bookkeeping flops aside)
         assert abs(f8 - f1) < 100
@@ -100,7 +99,7 @@ class TestCostAnalysisSemantics:
             ).compile()
 
         costs = calibrated_costs(lambda g: make(g), 5, scanned=True)
-        truth = cost_analysis(make(5))["flops"]
+        truth = make(5).cost_analysis()["flops"]
         assert costs.flops_per_device == pytest.approx(truth, rel=1e-6)
 
 

@@ -4,7 +4,7 @@ import numpy as np
 import pytest
 from hypothesis_compat import given, st
 
-from repro.core.toptree import PAD_COORD, build_top_tree, suggest_height
+from repro.core.toptree import PAD_COORD, build_top_tree, slab_len, suggest_height
 
 
 def _mk(n, d, seed=0):
@@ -110,3 +110,22 @@ def test_build_invariants_fuzz(n, d, h, seed):
     assert t.leaf_sizes().sum() == n
     assert t.leaf_sizes().min() >= 1
     assert sorted(t.orig_idx.tolist()) == list(range(n))
+
+
+@pytest.mark.parametrize(
+    "max_leaf,multiple,expect",
+    [(1, 8, 8), (196, 8, 200), (512, 8, 512), (513, 8, 1024),
+     (4883, 8, 5120), (600, 64, 1024), (40, 64, 64)],
+)
+def test_slab_len_whole_kernel_tiles(max_leaf, multiple, expect):
+    assert slab_len(max_leaf, multiple) == expect
+
+
+def test_planner_slab_bytes_match_build():
+    """The planner's byte estimate counts the slab the build pads to."""
+    from repro.api.planner import estimate_slab_bytes
+
+    pts = _mk(2400, 5)
+    t = build_top_tree(pts, 2)                  # leaves of 600 -> 1024
+    assert t.leaf_pad == 1024
+    assert estimate_slab_bytes(2400, 5, 2) == 4 * 1024 * 8 * 4
