@@ -72,6 +72,7 @@ from typing import Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import traversal
 from repro.core.chunked import ChunkedLeafStore
@@ -150,7 +151,7 @@ def _initial_advance(qpad, split_dim, split_val, *, first_leaf_heap):
     # leaf is deliberately NOT donated: the previous round's pending-leaf
     # map stays a live buffer so its async host readback can overlap the
     # round that consumes it (the double-buffered schedule sync).
-    donate_argnums=(0, 1, 3, 4),
+    donate_argnums=(0, 1, 3, 4, 5),
 )
 def _chunk_round(
     node,          # i32[m]   traversal heap position      (donated)
@@ -158,6 +159,7 @@ def _chunk_round(
     leaf,          # i32[m]   pending leaf per query, -1 done (NOT donated)
     knn_d,         # f32[m+1, k] running top-k sq-dists    (donated)
     knn_i,         # i32[m+1, k] reordered-global indices  (donated)
+    counts,        # i32[2]   running (units, rows) scanned (donated)
     qpad,          # f32[m, d_pad] zero-padded queries
     dev_slab,      # [C, L_pad, d_pad] resident chunk slab (f32/f16/u8 codes)
     lo,            # i32[] first leaf id of the chunk
@@ -183,7 +185,16 @@ def _chunk_round(
     Scans every query paused at a leaf of this chunk, merges its candidates,
     exits its leaf and advances it to its next pending leaf (which may be in
     any chunk).  Queries paused elsewhere are untouched.  Returns the
-    updated (node, fromc, leaf, knn_d, knn_i, n_units).
+    updated (node, fromc, leaf, knn_d, knn_i, counts): ``counts`` adds this
+    round's work units and the query rows that occupied a slot of them
+    (each in-chunk live query fills exactly one), so the host reads the
+    call's totals once instead of once per round.
+
+    The named scopes (``knn.plan``, ``knn.gather``, ``knn.scan``,
+    ``knn.merge``, ``knn.advance``) tag the device operations for the
+    profiler; they sit inside the block loop's body and around the advance,
+    never around the block loop itself, so that loop's own op carries none
+    and the time of its body is not counted twice.
 
     ``quant=True`` slabs hold storage codes: each gathered leaf tile is
     dequantized elementwise (codes * scale + offset, O(ub*L_pad*d) next to
@@ -202,74 +213,86 @@ def _chunk_round(
     # still keeps k columns
     kl = min(k, dev_slab.shape[1])
 
-    in_chunk = (leaf >= lo) & (leaf < lo + c)
-    local = jnp.where(in_chunk, leaf - lo, -1)
-    unit_leaf, unit_query, n_units = _build_plan(local, tq, c)
+    with jax.named_scope("knn.plan"):
+        in_chunk = (leaf >= lo) & (leaf < lo + c)
+        local = jnp.where(in_chunk, leaf - lo, -1)
+        unit_leaf, unit_query, n_units = _build_plan(local, tq, c)
+        rows = jnp.sum(local >= 0, dtype=jnp.int32)
+        counts = counts + jnp.stack([n_units, rows])
 
-    # pad the plan to a whole number of unit blocks so dynamic_slice starts
-    # stay in bounds; the occupied prefix [0, n_units) is what gets processed
-    w_rows = unit_leaf.shape[0]
-    w_pad = -(-w_rows // ub) * ub
-    unit_leaf = jnp.concatenate(
-        [unit_leaf, jnp.zeros((w_pad - w_rows,), jnp.int32)]
-    )
-    unit_query = jnp.concatenate(
-        [unit_query, jnp.full((w_pad - w_rows, tq), -1, jnp.int32)]
-    )
-    n_blocks = (n_units + ub - 1) // ub
+        # pad the plan to a whole number of unit blocks so dynamic_slice
+        # starts stay in bounds; the occupied prefix [0, n_units) is what
+        # gets processed
+        w_rows = unit_leaf.shape[0]
+        w_pad = -(-w_rows // ub) * ub
+        unit_leaf = jnp.concatenate(
+            [unit_leaf, jnp.zeros((w_pad - w_rows,), jnp.int32)]
+        )
+        unit_query = jnp.concatenate(
+            [unit_query, jnp.full((w_pad - w_rows, tq), -1, jnp.int32)]
+        )
+        n_blocks = (n_units + ub - 1) // ub
 
     def body(carry):
         i, knn_d, knn_i = carry
-        ul = jax.lax.dynamic_slice_in_dim(unit_leaf, i * ub, ub)
-        uq = jax.lax.dynamic_slice_in_dim(unit_query, i * ub, ub)
-        q_tiles = jnp.where(
-            (uq >= 0)[..., None], qpad[jnp.clip(uq, 0, m - 1)], 0.0
-        )                                                  # [ub, tq, d_pad]
-        gl = ul + lo
-        slabs = dev_slab[ul]                               # [ub, L_pad, d_pad]
-        if quant:
-            bits = q_dead[gl]                              # [ub, L_pad/8] u8
-            dead_tile = (
-                (bits[:, :, None]
-                 >> jnp.arange(7, -1, -1, dtype=jnp.uint8)) & 1
-            ).reshape(bits.shape[0], -1)[
-                :, : dev_slab.shape[1]
-            ].astype(bool)                                 # [ub, L_pad]
-            slabs = slabs.astype(jnp.float32)
-            if affine:
-                slabs = (
-                    slabs * q_scale[gl][:, None, :]
-                    + q_offset[gl][:, None, :]
+        with jax.named_scope("knn.gather"):
+            ul = jax.lax.dynamic_slice_in_dim(unit_leaf, i * ub, ub)
+            uq = jax.lax.dynamic_slice_in_dim(unit_query, i * ub, ub)
+            q_tiles = jnp.where(
+                (uq >= 0)[..., None], qpad[jnp.clip(uq, 0, m - 1)], 0.0
+            )                                              # [ub, tq, d_pad]
+            gl = ul + lo
+            slabs = dev_slab[ul]                           # [ub, L_pad, d_pad]
+            if quant:
+                bits = q_dead[gl]                          # [ub, L_pad/8] u8
+                dead_tile = (
+                    (bits[:, :, None]
+                     >> jnp.arange(7, -1, -1, dtype=jnp.uint8)) & 1
+                ).reshape(bits.shape[0], -1)[
+                    :, : dev_slab.shape[1]
+                ].astype(bool)                             # [ub, L_pad]
+                slabs = slabs.astype(jnp.float32)
+                if affine:
+                    slabs = (
+                        slabs * q_scale[gl][:, None, :]
+                        + q_offset[gl][:, None, :]
+                    )
+                slabs = jnp.where(
+                    dead_tile[:, :, None], jnp.float32(kops.PAD_COORD), slabs
                 )
-            slabs = jnp.where(
-                dead_tile[:, :, None], jnp.float32(kops.PAD_COORD), slabs
+        with jax.named_scope("knn.scan"):
+            nd, nli = kops.leaf_scan(
+                q_tiles, slabs, k=kl, backend=backend, tq=tq
             )
-        nd, nli = kops.leaf_scan(q_tiles, slabs, k=kl, backend=backend, tq=tq)
 
-        ustart = leaf_start[gl]
-        usize = leaf_size[gl]
-        valid = nli < usize[:, None, None]
-        if quant:
-            # tombstoned rows sit BELOW usize: drop any that the selection
-            # still surfaced (their PAD_COORD distance loses contests, but a
-            # sparse leaf can leave them in the top-k tail — and the exact
-            # re-rank would rescore them at their true coordinates)
-            sel_dead = dead_tile[
-                jnp.arange(ul.shape[0])[:, None, None], nli
-            ]
-            valid = valid & ~sel_dead
-        gidx = jnp.where(valid, nli + ustart[:, None, None], -1)
-        ndm = jnp.where(valid, nd, jnp.float32(kops.INVALID_DIST)).reshape(-1, kl)
-        nim = gidx.reshape(-1, kl)
-        flat_q = uq.reshape(-1)
-        safe_q = jnp.where(flat_q < 0, m, flat_q)
-        cd = jnp.concatenate([knn_d[safe_q], ndm], axis=1)
-        ci = jnp.concatenate([knn_i[safe_q], nim], axis=1)
-        neg, sel = jax.lax.top_k(-cd, k)
-        knn_d = knn_d.at[safe_q].set(-neg, mode="drop")
-        knn_i = knn_i.at[safe_q].set(
-            jnp.take_along_axis(ci, sel, axis=1), mode="drop"
-        )
+        with jax.named_scope("knn.merge"):
+            ustart = leaf_start[gl]
+            usize = leaf_size[gl]
+            valid = nli < usize[:, None, None]
+            if quant:
+                # tombstoned rows sit BELOW usize: drop any that the
+                # selection still surfaced (their PAD_COORD distance loses
+                # contests, but a sparse leaf can leave them in the top-k
+                # tail — and the exact re-rank would rescore them at their
+                # true coordinates)
+                sel_dead = dead_tile[
+                    jnp.arange(ul.shape[0])[:, None, None], nli
+                ]
+                valid = valid & ~sel_dead
+            gidx = jnp.where(valid, nli + ustart[:, None, None], -1)
+            ndm = jnp.where(
+                valid, nd, jnp.float32(kops.INVALID_DIST)
+            ).reshape(-1, kl)
+            nim = gidx.reshape(-1, kl)
+            flat_q = uq.reshape(-1)
+            safe_q = jnp.where(flat_q < 0, m, flat_q)
+            cd = jnp.concatenate([knn_d[safe_q], ndm], axis=1)
+            ci = jnp.concatenate([knn_i[safe_q], nim], axis=1)
+            neg, sel = jax.lax.top_k(-cd, k)
+            knn_d = knn_d.at[safe_q].set(-neg, mode="drop")
+            knn_i = knn_i.at[safe_q].set(
+                jnp.take_along_axis(ci, sel, axis=1), mode="drop"
+            )
         return i + 1, knn_d, knn_i
 
     _, knn_d, knn_i = jax.lax.while_loop(
@@ -279,17 +302,19 @@ def _chunk_round(
     # exit the just-scanned leaves (only this chunk's queries move) and
     # advance them to their next pending leaf; everyone else is frozen by
     # advance()'s own pause predicate (at-leaf, descending, or done)
-    st = traversal.TraversalState(node=node, fromc=fromc)
-    ex = traversal.exit_leaf(st, first_leaf_heap)
-    st = traversal.TraversalState(
-        node=jnp.where(in_chunk, ex.node, node).astype(jnp.int32),
-        fromc=jnp.where(in_chunk, ex.fromc, fromc).astype(jnp.int32),
-    )
-    radius = jnp.sqrt(knn_d[:m, k - 1]) + qeps
-    new_leaf, st = traversal.advance(
-        st, qpad, radius, split_dim, split_val, first_leaf_heap=first_leaf_heap
-    )
-    return st.node, st.fromc, new_leaf, knn_d, knn_i, n_units
+    with jax.named_scope("knn.advance"):
+        st = traversal.TraversalState(node=node, fromc=fromc)
+        ex = traversal.exit_leaf(st, first_leaf_heap)
+        st = traversal.TraversalState(
+            node=jnp.where(in_chunk, ex.node, node).astype(jnp.int32),
+            fromc=jnp.where(in_chunk, ex.fromc, fromc).astype(jnp.int32),
+        )
+        radius = jnp.sqrt(knn_d[:m, k - 1]) + qeps
+        new_leaf, st = traversal.advance(
+            st, qpad, radius, split_dim, split_val,
+            first_leaf_heap=first_leaf_heap,
+        )
+    return st.node, st.fromc, new_leaf, knn_d, knn_i, counts
 
 
 def chunk_round_cache_size() -> int:
@@ -390,6 +415,7 @@ class ChunkResidentEngine:
                 jnp.full((ms,), -1, jnp.int32),                    # leaf
                 jnp.full((ms + 1, k), kops.INVALID_DIST, jnp.float32),
                 jnp.full((ms + 1, k), -1, jnp.int32),
+                jnp.zeros((2,), jnp.int32),                        # counts
                 jnp.zeros((ms, d_pad), jnp.float32),               # qpad
             )
             return jax.device_put(arrs, dev)
@@ -397,14 +423,14 @@ class ChunkResidentEngine:
         qsc, qof, qdd, qeps, quant, affine = self._quant_args()
         for _cid, dev_slab, lo in self.store.stream([0]):
             for ms in shapes:
-                node, fromc, leaf, knn_d, knn_i, qpad = state_at(ms)
+                node, fromc, leaf, knn_d, knn_i, counts, qpad = state_at(ms)
                 with warnings.catch_warnings():
                     warnings.filterwarnings(
                         "ignore",
                         message="Some donated buffers were not usable",
                     )
                     _chunk_round(
-                        node, fromc, leaf, knn_d, knn_i,
+                        node, fromc, leaf, knn_d, knn_i, counts,
                         qpad, dev_slab, jnp.int32(lo),
                         self._leaf_start, self._leaf_size,
                         self._split_dim, self._split_val,
@@ -414,7 +440,7 @@ class ChunkResidentEngine:
                         affine=affine,
                     )
         for i, src in enumerate(shapes):
-            node, fromc, leaf, knn_d, knn_i, qpad = state_at(src)
+            node, fromc, leaf, knn_d, knn_i, _counts, qpad = state_at(src)
             for dst in shapes[i + 1:]:
                 _compact_state(
                     jnp.asarray(np.full((dst,), -1, np.int32)),
@@ -448,7 +474,7 @@ class ChunkResidentEngine:
 
     def run(
         self,
-        qpad: jnp.ndarray,      # f32[m, d_pad] zero-padded queries
+        queries: np.ndarray,    # f32[m, d] query rows (d <= the slab's d_pad)
         k: int,
         tq: int,
         buffer_size: int,
@@ -468,22 +494,15 @@ class ChunkResidentEngine:
         knn-row materialization is itself double-buffered (async D2H
         started at detection, completed just before the next dispatch), so
         the hook adds no extra device synchronization to the round loop.
+
+        The host phases are profiler spans (``knn.prepare``, ``knn.round``
+        with its ``knn.schedule``/``knn.dispatch``/``knn.harvest``,
+        ``knn.compact``, ``knn.drain``), on the device trace's clock; they
+        cost nothing while no profiler runs.
         """
-        m = qpad.shape[0]
+        m, d = queries.shape
         store = self.store
         first_leaf = self.first_leaf_heap
-
-        knn_d = jnp.full((m + 1, k), kops.INVALID_DIST, jnp.float32)
-        knn_i = jnp.full((m + 1, k), -1, jnp.int32)
-        leaf, node, fromc = _initial_advance(
-            qpad, self._split_dim, self._split_val, first_leaf_heap=first_leaf
-        )
-        # commit the round state to the store's device: round outputs are
-        # committed (the slab input is), and a committed/uncommitted avals
-        # mismatch would cost a second (pointless) round specialization
-        qpad, leaf, node, fromc, knn_d, knn_i = jax.device_put(
-            (qpad, leaf, node, fromc, knn_d, knn_i), store.device
-        )
 
         # full-m outputs; compaction scatters retired rows back here
         out_d = np.full((m, k), kops.INVALID_DIST, np.float32)
@@ -493,13 +512,11 @@ class ChunkResidentEngine:
         m_cur = m
 
         info = {
-            "rounds": 0, "chunk_rounds": 0, "units": 0,
+            "rounds": 0, "chunk_rounds": 0, "units": 0, "rows": 0,
             "queries_advanced": 0, "compactions": 0,
             "steady_rounds": 0, "tail_rounds": 0,
             "steady_s": 0.0, "tail_s": 0.0, "sync_wait_s": 0.0,
         }
-        copies_before = store.copies
-        unit_counts = []
         starve = np.zeros(store.n_chunks, np.int32)
 
         # ---- early-retirement reporting (the streaming engine's seam) ----
@@ -511,7 +528,6 @@ class ChunkResidentEngine:
         pending_emit = None
         if reported is not None:
             info["early_retired"] = 0
-            info["retire_emits"] = 0
 
         def flush_emit() -> None:
             nonlocal pending_emit
@@ -544,12 +560,11 @@ class ChunkResidentEngine:
                     ref.copy_to_host_async()
             pending_emit = (rows, rc, knn_d, knn_i)
             info["early_retired"] += int(rc.size)
-            info["retire_emits"] += 1
 
         qsc, qof, qdd, qeps, quant, affine = self._quant_args()
 
         def dispatch_round(visit: np.ndarray) -> None:
-            nonlocal node, fromc, leaf, knn_d, knn_i
+            nonlocal node, fromc, leaf, knn_d, knn_i, counts
             flush_emit()   # the round donates knn_d/knn_i: deliver first
             for _cid, dev_slab, lo in store.stream(visit.tolist()):
                 with warnings.catch_warnings():
@@ -560,8 +575,8 @@ class ChunkResidentEngine:
                         "ignore",
                         message="Some donated buffers were not usable",
                     )
-                    node, fromc, leaf, knn_d, knn_i, nu = _chunk_round(
-                        node, fromc, leaf, knn_d, knn_i,
+                    node, fromc, leaf, knn_d, knn_i, counts = _chunk_round(
+                        node, fromc, leaf, knn_d, knn_i, counts,
                         qpad, dev_slab, jnp.int32(lo),
                         self._leaf_start, self._leaf_size,
                         self._split_dim, self._split_val,
@@ -570,7 +585,6 @@ class ChunkResidentEngine:
                         ub=self.unit_block, backend=self.backend, quant=quant,
                         affine=affine,
                     )
-                unit_counts.append(nu)
                 info["chunk_rounds"] += 1
             info["rounds"] += 1
             info["queries_advanced"] += m_cur
@@ -586,15 +600,36 @@ class ChunkResidentEngine:
             info["sync_wait_s"] += time.perf_counter() - t0
             return out
 
-        # The schedule is double-buffered: `sched` is the host's (possibly
-        # one-round-stale) view of the pending-leaf map; `inflight` is the
-        # device map whose async readback overlaps the round in flight.
-        # Staleness is safe: retirement is monotone, so a stale map's live
-        # set is a superset of the true one, and the device recomputes the
-        # in-chunk mask at visit time.
-        sched = harvest(leaf)       # round 0: nothing to overlap yet
-        inflight = None
-        note_retired()
+        with TraceAnnotation("knn.prepare"):
+            d_pad = store.host.shape[2]
+            qpad = jnp.zeros((m, d_pad), jnp.float32).at[:, :d].set(
+                jnp.asarray(queries)
+            )
+            knn_d = jnp.full((m + 1, k), kops.INVALID_DIST, jnp.float32)
+            knn_i = jnp.full((m + 1, k), -1, jnp.int32)
+            leaf, node, fromc = _initial_advance(
+                qpad, self._split_dim, self._split_val,
+                first_leaf_heap=first_leaf,
+            )
+            # commit the round state to the store's device: round outputs
+            # are committed (the slab input is), and a committed/uncommitted
+            # avals mismatch would cost a second (pointless) round
+            # specialization.  `counts` sums (units, rows) over the rounds
+            # on the device; the host reads it once, at the drain.
+            qpad, leaf, node, fromc, knn_d, knn_i, counts = jax.device_put(
+                (qpad, leaf, node, fromc, knn_d, knn_i,
+                 np.zeros((2,), np.int32)),
+                store.device,
+            )
+            # The schedule is double-buffered: `sched` is the host's
+            # (possibly one-round-stale) view of the pending-leaf map;
+            # `inflight` is the device map whose async readback overlaps the
+            # round in flight.  Staleness is safe: retirement is monotone, so
+            # a stale map's live set is a superset of the true one, and the
+            # device recomputes the in-chunk mask at visit time.
+            sched = harvest(leaf)       # round 0: nothing to overlap yet
+            inflight = None
+            note_retired()
 
         while True:
             live_rows = np.nonzero(sched >= 0)[0]
@@ -602,74 +637,85 @@ class ChunkResidentEngine:
                 if inflight is not None:
                     # stale map says done — drain the pipeline and re-check
                     # against the freshest map before concluding
-                    sched, inflight = harvest(inflight), None
-                    note_retired()
+                    with TraceAnnotation("knn.drain"):
+                        sched, inflight = harvest(inflight), None
+                        note_retired()
                     continue
                 break
 
             if ladder and live_rows.size <= ladder[0]:
-                if inflight is not None:
-                    # compaction re-indexes rows: barrier the pipeline so
-                    # the gather uses the freshest (smallest) live set
-                    sched, inflight = harvest(inflight), None
-                    note_retired()
-                    continue
-                rung = ladder.pop(0)
-                while ladder and live_rows.size <= ladder[0]:
+                with TraceAnnotation("knn.compact"):
+                    if inflight is not None:
+                        # compaction re-indexes rows: barrier the pipeline
+                        # so the gather uses the freshest (smallest) live set
+                        sched, inflight = harvest(inflight), None
+                        note_retired()
+                        continue
                     rung = ladder.pop(0)
-                # retire everything the current shape holds (live rows are
-                # re-scattered at the next compaction or at exit); this
-                # blocks on all in-flight rounds, so it is accounted as
-                # sync wait like the schedule readbacks
-                t0 = time.perf_counter()
-                out_d[orig] = np.asarray(knn_d)[: orig.size]
-                out_i[orig] = np.asarray(knn_i)[: orig.size]
-                info["sync_wait_s"] += time.perf_counter() - t0
-                sel = np.full((rung,), -1, np.int32)
-                sel[: live_rows.size] = live_rows
-                qpad, leaf, node, fromc, knn_d, knn_i = _compact_state(
-                    jnp.asarray(sel), qpad, leaf, node, fromc, knn_d, knn_i,
-                    mc=rung,
-                )
-                orig = orig[live_rows]
-                new_sched = np.full((rung,), -1, sched.dtype)
-                new_sched[: live_rows.size] = sched[live_rows]
-                sched = new_sched
-                m_cur = rung
-                info["compactions"] += 1
+                    while ladder and live_rows.size <= ladder[0]:
+                        rung = ladder.pop(0)
+                    # retire everything the current shape holds (live rows
+                    # are re-scattered at the next compaction or at exit);
+                    # this blocks on all in-flight rounds, so it is
+                    # accounted as sync wait like the schedule readbacks
+                    t0 = time.perf_counter()
+                    out_d[orig] = np.asarray(knn_d)[: orig.size]
+                    out_i[orig] = np.asarray(knn_i)[: orig.size]
+                    info["sync_wait_s"] += time.perf_counter() - t0
+                    sel = np.full((rung,), -1, np.int32)
+                    sel[: live_rows.size] = live_rows
+                    qpad, leaf, node, fromc, knn_d, knn_i = _compact_state(
+                        jnp.asarray(sel), qpad, leaf, node, fromc, knn_d,
+                        knn_i, mc=rung,
+                    )
+                    orig = orig[live_rows]
+                    new_sched = np.full((rung,), -1, sched.dtype)
+                    new_sched[: live_rows.size] = sched[live_rows]
+                    sched = new_sched
+                    m_cur = rung
+                    info["compactions"] += 1
                 continue
 
-            # per-round host work is over the LIVE queries only: mask, then
-            # a precomputed leaf->chunk table lookup (no full-m searchsorted)
-            threshold = max(1, min(int(buffer_size), m_cur) // 2)
-            counts = np.bincount(
-                self._leaf_chunk[sched[live_rows]], minlength=store.n_chunks
-            )
-            t0 = time.perf_counter()
-            wait0 = info["sync_wait_s"]
-            dispatch_round(self._visit_order(counts, threshold, starve))
-            # overlap: complete the PREVIOUS round's readback while this
-            # round computes, then start this round's readback
-            if inflight is not None:
-                sched = harvest(inflight)
-                note_retired()
-            inflight = leaf
-            if hasattr(inflight, "copy_to_host_async"):
-                inflight.copy_to_host_async()
-            # blocked readback time is accounted in sync_wait_s only, so
-            # the phase buckets sum to the loop wall time (and the
-            # calibrator's round_s = steady_s / rounds stays copy-free)
-            dt = time.perf_counter() - t0 - (info["sync_wait_s"] - wait0)
-            info["steady_s" if m_cur == m else "tail_s"] += dt
+            with TraceAnnotation("knn.round", round=info["rounds"],
+                                 rung=m_cur):
+                # per-round host work is over the LIVE queries only: a
+                # precomputed leaf->chunk table lookup (no full-m
+                # searchsorted)
+                with TraceAnnotation("knn.schedule"):
+                    threshold = max(1, min(int(buffer_size), m_cur) // 2)
+                    chunk_counts = np.bincount(
+                        self._leaf_chunk[sched[live_rows]],
+                        minlength=store.n_chunks,
+                    )
+                    visit = self._visit_order(chunk_counts, threshold, starve)
+                t0 = time.perf_counter()
+                wait0 = info["sync_wait_s"]
+                with TraceAnnotation("knn.dispatch"):
+                    dispatch_round(visit)
+                # overlap: complete the PREVIOUS round's readback while this
+                # round computes, then start this round's readback
+                if inflight is not None:
+                    with TraceAnnotation("knn.harvest"):
+                        sched = harvest(inflight)
+                        note_retired()
+                inflight = leaf
+                if hasattr(inflight, "copy_to_host_async"):
+                    inflight.copy_to_host_async()
+                # blocked readback time is accounted in sync_wait_s only, so
+                # the phase buckets sum to the loop wall time (and the
+                # calibrator's round_s = steady_s / rounds stays copy-free)
+                dt = time.perf_counter() - t0 - (info["sync_wait_s"] - wait0)
+                info["steady_s" if m_cur == m else "tail_s"] += dt
 
-        out_d[orig] = np.asarray(knn_d)[: orig.size]
-        out_i[orig] = np.asarray(knn_i)[: orig.size]
+        with TraceAnnotation("knn.drain"):
+            out_d[orig] = np.asarray(knn_d)[: orig.size]
+            out_i[orig] = np.asarray(knn_i)[: orig.size]
+            info["units"], info["rows"] = (int(x) for x in np.asarray(counts))
+            if reported is not None:
+                flush_emit()
         if reported is not None:
-            flush_emit()
             rest = np.nonzero(~reported)[0]
             if rest.size:
                 on_retire(rest, out_d[rest], out_i[rest])
                 reported[rest] = True
-        info["units"] = int(sum(int(u) for u in unit_counts))
-        info["chunk_copies"] = store.copies - copies_before
         return out_d, out_i, info

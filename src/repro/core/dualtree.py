@@ -60,6 +60,7 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.chunked import ChunkedLeafStore
 from repro.core.lazysearch import SearchStats
@@ -214,22 +215,25 @@ def _pair_hist_kernel(aslab, bslab, ia, ib, sa, sb, edges):
     counts (PAD x PAD rows can cancel to a fake 0 distance, so they are
     masked to +inf, which searchsorted discards).  Returns i32[P, E]
     integer counts for E = len(edges) - 1 bins; the last edge is closed,
-    matching np.histogram.
+    matching np.histogram.  The named scopes ``pc.distance`` and ``pc.bin``
+    tag its device operations for the profiler.
     """
     P = ia.shape[0]
     E = edges.shape[0] - 1
-    d2 = _pairwise_d2(aslab[ia], bslab[ib])
-    rows = jnp.arange(d2.shape[1], dtype=jnp.int32)
-    cols = jnp.arange(d2.shape[2], dtype=jnp.int32)
-    valid = (rows[None, :, None] < sa[:, None, None]) & (
-        cols[None, None, :] < sb[:, None, None]
-    )
-    dist = jnp.where(valid, jnp.sqrt(d2), jnp.inf)
-    flat = dist.reshape(P, -1)
-    r = jnp.searchsorted(edges, flat, side="right").astype(jnp.int32)
-    r = jnp.where(flat == edges[-1], E, r)  # last bin is closed
-    hist = jax.vmap(lambda b: jnp.bincount(b, length=E + 2))(r)
-    return hist[:, 1:E + 1]
+    with jax.named_scope("pc.distance"):
+        d2 = _pairwise_d2(aslab[ia], bslab[ib])
+        rows = jnp.arange(d2.shape[1], dtype=jnp.int32)
+        cols = jnp.arange(d2.shape[2], dtype=jnp.int32)
+        valid = (rows[None, :, None] < sa[:, None, None]) & (
+            cols[None, None, :] < sb[:, None, None]
+        )
+        dist = jnp.where(valid, jnp.sqrt(d2), jnp.inf)
+    with jax.named_scope("pc.bin"):
+        flat = dist.reshape(P, -1)
+        r = jnp.searchsorted(edges, flat, side="right").astype(jnp.int32)
+        r = jnp.where(flat == edges[-1], E, r)  # last bin is closed
+        hist = jax.vmap(lambda b: jnp.bincount(b, length=E + 2))(r)
+        return hist[:, 1:E + 1]
 
 
 def dualtree_cache_size() -> int:
@@ -253,7 +257,6 @@ class _TraceStats:
     """Mutable counters one traversal accumulates, frozen into SearchStats."""
 
     levels: int = 0
-    pairs_pruned: int = 0
     leaf_pairs: int = 0
     batches: int = 0
     chunk_visits: int = 0
@@ -367,7 +370,6 @@ class DualTree:
                 break
             dmin2, dmax2 = _box_dist2(qb, u, rb, v)
             drop = prune(u, v, dmin2, dmax2)
-            trace.pairs_pruned += int(drop.sum())
             u, v = u[~drop], v[~drop]
             q_leaf = u >= qb.first_leaf
             r_leaf = v >= rb.first_leaf
@@ -429,7 +431,6 @@ class DualTree:
                 break
             dmin2, dmax2 = _box_dist2(rb, a, rb, b)
             drop = prune(a, b, w, dmin2, dmax2)
-            trace.pairs_pruned += int(drop.sum())
             a, b, w = a[~drop], b[~drop], w[~drop]
             leaf = a >= rb.first_leaf  # a <= b and leaves share one level,
             done = leaf & (b >= rb.first_leaf)
@@ -654,79 +655,89 @@ class DualTree:
         """2-point correlation: histogram (np.histogram semantics) of the
         distances of all ordered pairs (i, j), i != j, of the reference
         set against itself.  Returns (hist i64[E], stats)."""
-        edges = np.asarray(edges, np.float64).ravel()
-        if edges.size < 2 or not np.all(np.diff(edges) > 0):
-            raise ValueError("edges must be >= 2 strictly increasing values")
-        if edges[0] < 0:
-            raise ValueError("distance edges must be >= 0")
-        E = edges.size - 1
-        trace = _TraceStats()
-        hist = np.zeros(E, np.int64)
-        e2 = edges * edges
-        rb = self.bounds
+        with TraceAnnotation("pc.call"):
+            edges = np.asarray(edges, np.float64).ravel()
+            if edges.size < 2 or not np.all(np.diff(edges) > 0):
+                raise ValueError(
+                    "edges must be >= 2 strictly increasing values"
+                )
+            if edges[0] < 0:
+                raise ValueError("distance edges must be >= 0")
+            E = edges.size - 1
+            trace = _TraceStats()
+            hist = np.zeros(E, np.int64)
+            e2 = edges * edges
+            rb = self.bounds
 
-        def prune(a, b, w, dmin2, dmax2):
-            below = dmax2 < e2[0]
-            above = dmin2 > e2[-1]
-            bl = np.searchsorted(e2, dmin2, side="right")
-            bh = np.searchsorted(e2, dmax2, side="right")
-            onebin = (bl == bh) & (bl >= 1) & (bl <= E)
-            if onebin.any():
-                width = (
-                    w[onebin] * rb.count[a[onebin]] * rb.count[b[onebin]]
-                )
-                np.add.at(hist, bl[onebin] - 1, width)
-            return below | above | onebin
+            def prune(a, b, w, dmin2, dmax2):
+                below = dmax2 < e2[0]
+                above = dmin2 > e2[-1]
+                bl = np.searchsorted(e2, dmin2, side="right")
+                bh = np.searchsorted(e2, dmax2, side="right")
+                onebin = (bl == bh) & (bl >= 1) & (bl <= E)
+                if onebin.any():
+                    width = (
+                        w[onebin] * rb.count[a[onebin]] * rb.count[b[onebin]]
+                    )
+                    np.add.at(hist, bl[onebin] - 1, width)
+                return below | above | onebin
 
-        la, lb, lw = self._self_leaf_pairs(prune, trace)
-        edges_dev = jnp.asarray(edges, jnp.float32)
-        sizes = self._leaf_sizes
-        # group leaf pairs by their (chunk_a, chunk_b) so at most two chunk
-        # slabs are device-resident at a time (the store's own slot count)
-        ca = np.asarray(self.store.chunk_of_leaf(la))
-        cb = np.asarray(self.store.chunk_of_leaf(lb))
-        order = np.lexsort((lb, la, cb, ca))
-        la, lb, lw, ca, cb = la[order], lb[order], lw[order], ca[order], cb[order]
-        group = np.concatenate(
-            [[0], np.nonzero((np.diff(ca) != 0) | (np.diff(cb) != 0))[0] + 1,
-             [la.size]]
-        )
-        for g in range(group.size - 1):
-            glo, ghi = int(group[g]), int(group[g + 1])
-            if glo == ghi:
-                continue
-            ja, jb = int(ca[glo]), int(cb[glo])
-            buf_a, lo_a = self._chunk_slab(ja, trace)
-            buf_b, lo_b = self._chunk_slab(jb, trace)
-            for lo, hi, rung in self._batches(ghi - glo):
-                lo, hi = glo + lo, glo + hi
-                iq, ir = self._pad_pairs(
-                    (la - lo_a, lb - lo_b), lo, hi, rung
+            with TraceAnnotation("pc.frontier"):
+                la, lb, lw = self._self_leaf_pairs(prune, trace)
+                # group leaf pairs by their (chunk_a, chunk_b) so at most two
+                # chunk slabs are device-resident at a time (the store's own
+                # slot count)
+                ca = np.asarray(self.store.chunk_of_leaf(la))
+                cb = np.asarray(self.store.chunk_of_leaf(lb))
+                order = np.lexsort((lb, la, cb, ca))
+                la, lb, lw = la[order], lb[order], lw[order]
+                ca, cb = ca[order], cb[order]
+                cut = (np.diff(ca) != 0) | (np.diff(cb) != 0)
+                group = np.concatenate(
+                    [[0], np.nonzero(cut)[0] + 1, [la.size]]
                 )
-                sa, sb = self._pad_pairs((sizes[la], sizes[lb]), lo, hi, rung)
-                h = np.asarray(
-                    _pair_hist_kernel(
-                        buf_a, buf_b, iq, ir, sa, sb, edges_dev
-                    ),
-                    np.int64,
-                )
-                trace.shapes.add((rung, "pc"))
-                trace.batches += 1
-                real = hi - lo
-                trace.leaf_pairs += real
-                trace.points_paired += int(
-                    (sizes[la[lo:hi]] * sizes[lb[lo:hi]]).sum()
-                )
-                hist += (h[:real] * lw[lo:hi, None]).sum(axis=0)
-        # the traversal counts ordered pairs INCLUDING the diagonal; the
-        # n self-pairs sit at distance 0 — remove them from whichever bin
-        # holds 0 (if any)
-        zbin = np.searchsorted(edges, 0.0, side="right")
-        if zbin == 0 and edges[0] == 0.0:
-            zbin = 1
-        if 1 <= zbin <= E:
-            hist[zbin - 1] -= self.tree.n
-        return hist, trace.freeze(0)
+            edges_dev = jnp.asarray(edges, jnp.float32)
+            sizes = self._leaf_sizes
+            for g in range(group.size - 1):
+                glo, ghi = int(group[g]), int(group[g + 1])
+                if glo == ghi:
+                    continue
+                ja, jb = int(ca[glo]), int(cb[glo])
+                buf_a, lo_a = self._chunk_slab(ja, trace)
+                buf_b, lo_b = self._chunk_slab(jb, trace)
+                for lo, hi, rung in self._batches(ghi - glo):
+                    lo, hi = glo + lo, glo + hi
+                    with TraceAnnotation("pc.batch", batch=trace.batches,
+                                         rung=rung):
+                        with TraceAnnotation("pc.dispatch"):
+                            iq, ir = self._pad_pairs(
+                                (la - lo_a, lb - lo_b), lo, hi, rung
+                            )
+                            sa, sb = self._pad_pairs(
+                                (sizes[la], sizes[lb]), lo, hi, rung
+                            )
+                            h_dev = _pair_hist_kernel(
+                                buf_a, buf_b, iq, ir, sa, sb, edges_dev
+                            )
+                        with TraceAnnotation("pc.readback"):
+                            h = np.asarray(h_dev, np.int64)
+                            trace.shapes.add((rung, "pc"))
+                            trace.batches += 1
+                            real = hi - lo
+                            trace.leaf_pairs += real
+                            trace.points_paired += int(
+                                (sizes[la[lo:hi]] * sizes[lb[lo:hi]]).sum()
+                            )
+                            hist += (h[:real] * lw[lo:hi, None]).sum(axis=0)
+            # the traversal counts ordered pairs INCLUDING the diagonal; the
+            # n self-pairs sit at distance 0 — remove them from whichever bin
+            # holds 0 (if any)
+            zbin = np.searchsorted(edges, 0.0, side="right")
+            if zbin == 0 and edges[0] == 0.0:
+                zbin = 1
+            if 1 <= zbin <= E:
+                hist[zbin - 1] -= self.tree.n
+            return hist, trace.freeze(0)
 
     # -- chunk streaming helpers ----------------------------------------
     def _stream_ref(self, ql, rl, trace: _TraceStats):

@@ -42,6 +42,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import traversal
 from repro.core.buffers import LeafBuffers, QueryQueues, build_work_plan
@@ -111,6 +112,8 @@ class SearchStats:
     compactions: int = 0     # ladder rungs entered
     steady_rounds: int = 0   # rounds at the full batch shape
     tail_rounds: int = 0     # rounds at a compacted ladder rung
+    rows_scanned: int = 0    # query rows occupying a slot of a scanned
+                             # tile, summed over rounds (<= units * tq)
     steady_s: float = 0.0    # wall seconds in steady-state rounds
     tail_s: float = 0.0      # wall seconds in tail (compacted) rounds
     sync_wait_s: float = 0.0  # wall seconds blocked on schedule readbacks
@@ -137,10 +140,32 @@ class _StatsBuilder:
         self.compactions = 0
         self.steady_rounds = 0
         self.tail_rounds = 0
+        self.rows_scanned = 0
         self.steady_s = 0.0
         self.tail_s = 0.0
         self.sync_wait_s = 0.0
         self.early_retired = 0
+
+    @classmethod
+    def from_engine(cls, info: dict, slab_rows: int) -> "_StatsBuilder":
+        """The counters of one ``ChunkResidentEngine.run`` (its ``info``);
+        ``slab_rows`` is the padded leaf length a work unit scans."""
+        sb = cls()
+        sb.iterations = info["rounds"]
+        sb.flushes = info["rounds"]
+        sb.chunk_rounds = info["chunk_rounds"]
+        sb.units_scanned = info["units"]
+        sb.points_scanned = info["units"] * slab_rows
+        sb.rows_scanned = info["rows"]
+        sb.queries_advanced = info["queries_advanced"]
+        sb.compactions = info["compactions"]
+        sb.steady_rounds = info["steady_rounds"]
+        sb.tail_rounds = info["tail_rounds"]
+        sb.steady_s = info["steady_s"]
+        sb.tail_s = info["tail_s"]
+        sb.sync_wait_s = info["sync_wait_s"]
+        sb.early_retired = info.get("early_retired", 0)
+        return sb
 
     def freeze(self) -> SearchStats:
         return SearchStats(
@@ -154,6 +179,7 @@ class _StatsBuilder:
             compactions=self.compactions,
             steady_rounds=self.steady_rounds,
             tail_rounds=self.tail_rounds,
+            rows_scanned=self.rows_scanned,
             steady_s=self.steady_s,
             tail_s=self.tail_s,
             sync_wait_s=self.sync_wait_s,
@@ -496,33 +522,25 @@ class BufferKDTree:
             raise ValueError(f"query dim {d} != reference dim {self.d}")
         if k > self.n:
             raise ValueError(f"k={k} > n={self.n}")
-        sb = _StatsBuilder()
         first_leaf = self.tree.first_leaf_heap
         tq = self.tile_q
         k_eff = self._engine_k(k)
 
-        qs = jnp.asarray(queries)
-
         if self.engine == "chunked":
-            qpad_m = jnp.zeros((m, self.d_pad), jnp.float32).at[:, :d].set(qs)
-            _d2, gi, info = self._engine.run(
-                qpad_m, k_eff, self.engine_tile_q, self.buffer_size
-            )
-            sb.iterations = info["rounds"]
-            sb.flushes = info["rounds"]
-            sb.chunk_rounds = info["chunk_rounds"]
-            sb.units_scanned = info["units"]
-            sb.points_scanned = info["units"] * self.store.host.shape[1]
-            sb.queries_advanced = info["queries_advanced"]
-            sb.compactions = info["compactions"]
-            sb.steady_rounds = info["steady_rounds"]
-            sb.tail_rounds = info["tail_rounds"]
-            sb.steady_s = info["steady_s"]
-            sb.tail_s = info["tail_s"]
-            sb.sync_wait_s = info["sync_wait_s"]
-            self._last_stats = sb.freeze()
-            return self._finalize(gi, queries, k)
+            # profiler spans: the call, the engine's phases inside ``run``,
+            # and the host rescoring (docs/OPERATIONS.md)
+            with TraceAnnotation("knn.query"):
+                _d2, gi, info = self._engine.run(
+                    queries, k_eff, self.engine_tile_q, self.buffer_size
+                )
+                self._last_stats = _StatsBuilder.from_engine(
+                    info, self.store.host.shape[1]
+                ).freeze()
+                with TraceAnnotation("knn.rescore"):
+                    return self._finalize(gi, queries, k)
 
+        sb = _StatsBuilder()
+        qs = jnp.asarray(queries)
         qpad = jnp.zeros((m + 1, self.d_pad), jnp.float32)
         qpad = qpad.at[:m, :d].set(qs)
 

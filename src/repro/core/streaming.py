@@ -21,7 +21,6 @@ from __future__ import annotations
 
 from typing import Callable, Tuple
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.lazysearch import (
@@ -90,27 +89,11 @@ def stream_query(
         out_i[rows] = idx
         emit(rows, dists, idx)
 
-    qpad = jnp.zeros((m, bkd.d_pad), jnp.float32).at[:, :d].set(
-        jnp.asarray(queries)
-    )
     _d2, _gi, info = bkd._engine.run(
-        qpad, k_eff, bkd.engine_tile_q, bkd.buffer_size, on_retire=on_retire
+        queries, k_eff, bkd.engine_tile_q, bkd.buffer_size, on_retire=on_retire
     )
 
-    sb = _StatsBuilder()
-    sb.iterations = info["rounds"]
-    sb.flushes = info["rounds"]
-    sb.chunk_rounds = info["chunk_rounds"]
-    sb.units_scanned = info["units"]
-    sb.points_scanned = info["units"] * bkd.store.host.shape[1]
-    sb.queries_advanced = info["queries_advanced"]
-    sb.compactions = info["compactions"]
-    sb.steady_rounds = info["steady_rounds"]
-    sb.tail_rounds = info["tail_rounds"]
-    sb.steady_s = info["steady_s"]
-    sb.tail_s = info["tail_s"]
-    sb.sync_wait_s = info["sync_wait_s"]
-    sb.early_retired = info.get("early_retired", 0)
+    sb = _StatsBuilder.from_engine(info, bkd.store.host.shape[1])
     stats = sb.freeze()
     bkd._last_stats = stats
     return out_d, out_i, stats

@@ -93,6 +93,7 @@ def test_chunk_round_compiles_at_smoke_shapes(one_chip):
         _sds((m,), i32, one_chip),                       # leaf
         _sds((m + 1, K), f32, one_chip),                 # knn_d
         _sds((m + 1, K), i32, one_chip),                 # knn_i
+        _sds((2,), i32, one_chip),                       # counts
         _sds((m, SMOKE_D_PAD), f32, one_chip),           # qpad
         _sds((c, SMOKE_L_PAD, SMOKE_D_PAD), f32, one_chip),  # dev_slab
         _sds((), i32, one_chip),                         # lo
