@@ -213,13 +213,17 @@ def _pair_hist_kernel(aslab, bslab, ia, ib, sa, sb, edges):
 
     Both sides gather from chunk slabs; sa/sb i32[P] are the real row
     counts (PAD x PAD rows can cancel to a fake 0 distance, so they are
-    masked to +inf, which searchsorted discards).  Returns i32[P, E]
-    integer counts for E = len(edges) - 1 bins; the last edge is closed,
-    matching np.histogram.  The named scopes ``pc.distance`` and ``pc.bin``
-    tag its device operations for the profiler.
+    masked to +inf, which no bin holds).  Returns i32[P, E] integer
+    counts for E = len(edges) - 1 bins; the last edge is closed, matching
+    np.histogram.
+
+    Bins are counted by comparison, not by a scatter: per pair,
+    G[j] = #{dist >= e_j} for j < E and G[E] = #{dist > e_E}, and bin i
+    is G[i] - G[i+1].  Distances below e_0 or above e_E (+inf included)
+    fall in every term or in none, so they cancel.  The named scopes
+    ``pc.distance`` and ``pc.bin`` tag its device operations for the
+    profiler.
     """
-    P = ia.shape[0]
-    E = edges.shape[0] - 1
     with jax.named_scope("pc.distance"):
         d2 = _pairwise_d2(aslab[ia], bslab[ib])
         rows = jnp.arange(d2.shape[1], dtype=jnp.int32)
@@ -229,11 +233,11 @@ def _pair_hist_kernel(aslab, bslab, ia, ib, sa, sb, edges):
         )
         dist = jnp.where(valid, jnp.sqrt(d2), jnp.inf)
     with jax.named_scope("pc.bin"):
-        flat = dist.reshape(P, -1)
-        r = jnp.searchsorted(edges, flat, side="right").astype(jnp.int32)
-        r = jnp.where(flat == edges[-1], E, r)  # last bin is closed
-        hist = jax.vmap(lambda b: jnp.bincount(b, length=E + 2))(r)
-        return hist[:, 1:E + 1]
+        ge = jnp.sum(dist[:, None] >= edges[:-1, None, None], axis=(2, 3),
+                     dtype=jnp.int32)
+        gt = jnp.sum(dist > edges[-1], axis=(1, 2), dtype=jnp.int32)
+        g = jnp.concatenate([ge, gt[:, None]], axis=1)
+        return g[:, :-1] - g[:, 1:]
 
 
 def dualtree_cache_size() -> int:

@@ -9,6 +9,10 @@ against its declared contract: ``|approx - exact| <= rtol*exact + atol``
 (plus fp32 kernel rounding slack).
 """
 
+import re
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -16,6 +20,8 @@ from repro.core.chunked import ChunkedLeafStore
 from repro.core.dualtree import (
     PAIR_RUNGS,
     DualTree,
+    _pair_hist_kernel,
+    _pairwise_d2,
     dualtree_cache_size,
     kde_brute,
     node_bounds,
@@ -23,7 +29,7 @@ from repro.core.dualtree import (
     radius_brute,
 )
 from repro.core.lazysearch import SearchStats
-from repro.core.toptree import build_top_tree
+from repro.core.toptree import PAD_COORD, build_top_tree
 
 # non-integer-squared boundaries (see module doc)
 EDGES = np.array([0.5, 3.5, 7.5, 11.5, 16.5, 25.5])
@@ -236,6 +242,76 @@ class TestPairCount:
         for bad in ([1.0], [2.0, 1.0], [-1.0, 2.0]):
             with pytest.raises(ValueError):
                 dual.pair_count(np.asarray(bad, np.float64))
+
+
+@jax.jit
+def _masked_dist(aslab, bslab, ia, ib, sa, sb):
+    """The kernel's distances: pad rows beyond sa/sb masked to +inf."""
+    d2 = _pairwise_d2(aslab[ia], bslab[ib])
+    rows = jnp.arange(d2.shape[1])
+    cols = jnp.arange(d2.shape[2])
+    valid = (rows[None, :, None] < sa[:, None, None]) & (
+        cols[None, None, :] < sb[:, None, None]
+    )
+    return jnp.where(valid, jnp.sqrt(d2), jnp.inf)
+
+
+@jax.jit
+def _bincount_hist(dist, edges):
+    """The scatter formulation: searchsorted, the closed last edge, then one
+    bincount per pair over E + 2 slots, the outer two dropped."""
+    E = edges.shape[0] - 1
+    flat = dist.reshape(dist.shape[0], -1)
+    r = jnp.searchsorted(edges, flat, side="right").astype(jnp.int32)
+    r = jnp.where(flat == edges[-1], E, r)
+    hist = jax.vmap(lambda b: jnp.bincount(b, length=E + 2))(r)
+    return hist[:, 1:E + 1]
+
+
+class TestPairHistKernel:
+    @pytest.mark.parametrize("n_edges", [2, 9, 33])
+    def test_matches_bincount_formulation(self, n_edges):
+        rng = np.random.default_rng(n_edges)
+        n_leaves, lp, P = 6, 24, 16
+        sizes = rng.integers(1, lp + 1, n_leaves)
+        sizes[0] = lp
+        slab = rng.random((n_leaves, lp, 8)).astype(np.float32) * 3.0
+        for j, s in enumerate(sizes):
+            slab[j, s:] = PAD_COORD
+        ia = rng.integers(0, n_leaves, P).astype(np.int32)
+        ib = rng.integers(0, n_leaves, P).astype(np.int32)
+        sa, sb = sizes[ia].astype(np.int32), sizes[ib].astype(np.int32)
+        sa[0] = 0  # an empty side
+        args = (slab, slab, ia, ib, sa, sb)
+        dist = np.asarray(_masked_dist(*args))
+        # edges ARE realized f32 distances, so values sit exactly on every
+        # edge, the closed last one included, with finite values below e_0
+        # and above e_E and +inf on the masked rows
+        real = np.unique(dist[np.isfinite(dist)])
+        pick = np.linspace(0.1, 0.9, n_edges) * (real.size - 1)
+        edges = real[pick.astype(np.int64)]
+        assert np.all(np.diff(edges) > 0)
+        assert np.all(np.isin(edges, dist))
+        assert (dist < edges[0]).any() and np.isinf(dist).any()
+        assert (np.isfinite(dist) & (dist > edges[-1])).any()
+        got = np.asarray(_pair_hist_kernel(*args, edges))
+        want = np.asarray(_bincount_hist(dist, edges))
+        assert got.dtype == np.int32 and got.shape == (P, n_edges - 1)
+        assert np.array_equal(got, want)
+        assert not got[0].any()
+
+    @pytest.mark.parametrize("rung", [8, 128])
+    def test_lowers_without_scatter(self, rung):
+        slab = jax.ShapeDtypeStruct((4, 256, 8), jnp.float32)
+        ids = jax.ShapeDtypeStruct((rung,), jnp.int32)
+        edges = jax.ShapeDtypeStruct((9,), jnp.float32)
+        text = _pair_hist_kernel.lower(
+            slab, slab, ids, ids, ids, ids, edges
+        ).as_text()
+        assert not re.search(r"\bscatter", text)
+        # the witness: the formulation it replaced does lower to one
+        dist = jax.ShapeDtypeStruct((rung, 256, 256), jnp.float32)
+        assert re.search(r"\bscatter", _bincount_hist.lower(dist, edges).as_text())
 
 
 class TestRecompileDiscipline:

@@ -13,6 +13,7 @@ imports this file.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -124,3 +125,20 @@ def test_pair_hist_kernel_compiles_at_top_rung(one_chip):
         _sds((PAIR_EDGES,), jnp.float32, one_chip),
     ).compile()
     assert compiled.as_text()
+
+
+@pytest.mark.parametrize("n_edges", [PAIR_EDGES, 65])
+def test_pair_hist_kernel_counts_without_scatter(one_chip, n_edges):
+    """Bins are counted by comparison at the 2PCF cell's batch shape (256-row
+    leaves): no scatter, and no temporary beyond one batch's distances at any
+    edge count."""
+    rung, lp = PAIR_RUNGS[-1], 256
+    slab = _sds((512, lp, PAIR_D_PAD), jnp.float32, one_chip)
+    ids = _sds((rung,), jnp.int32, one_chip)
+    compiled = _pair_hist_kernel.lower(
+        slab, slab, ids, ids, ids, ids,
+        _sds((n_edges,), jnp.float32, one_chip),
+    ).compile()
+    # an op, not this test's name in the source locations
+    assert not re.search(r"\bscatter", compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes <= rung * lp * lp * 4
