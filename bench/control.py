@@ -4,7 +4,8 @@
     python bench/control.py --workload <name> --seeds 1,2,3
 
 For each seed it makes the cell's data as a run would, puts the control of
-``bench.lib.controls`` in the program's place (the reference one precision
+the cell's driver (``bench/controls/<driver>.py``, built on
+``bench.lib.controls``) in the program's place (the reference one precision
 below the configuration's), and compares it with the plain reference by the
 cell's own comparison.  It prints each number beside its limit; the control
 has to exceed a limit on every seed.  The benchmark's runs never run this.
@@ -17,40 +18,31 @@ import os
 import sys
 import time
 
-import numpy as np
-
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from bench.lib import controls, harness, oracles  # noqa: E402
+from bench.lib import harness  # noqa: E402
 
 
 def read_control(workload: str, seed: int, rehearse: bool = False) -> dict:
-    """The control's numbers for one seed: {name: {"value", "limit"}}."""
+    """The control's numbers for one seed: {name: {"value", "limit"}}.
+
+    The control of a driver is ``bench/controls/<driver>.py``: ``read(d,
+    seed)`` gives the compared numbers for the driver ``d`` once it has
+    made the seed's data."""
     spec = harness.resolve(harness.load_benchmark(), workload, rehearse)
     cfg, traffic = spec["config"], spec["traffic"]
-    drv = harness.load_module(os.path.join(
-        harness.BENCH, "drivers", traffic["driver"] + ".py"))
+    name = traffic["driver"]
+    drv = harness.load_module(os.path.join(harness.BENCH, "drivers",
+                                           name + ".py"))
+    ctl = harness.load_module(os.path.join(harness.BENCH, "controls",
+                                           name + ".py"))
     d = drv.Driver(cfg, traffic, seed, log=harness.log)
     d.make_data()
-    limits = cfg["limits"]
-    if traffic["driver"] == "knn_batch":
-        s = int(traffic["check_queries"])
-        flat = d.pool.reshape(-1, d.pool.shape[-1])
-        rng = np.random.default_rng(oracles.seed_sequence(seed, 7))
-        queries = flat[np.sort(rng.choice(flat.shape[0], s, replace=False))]
-        ref = oracles.knn_oracle(d.points, queries, d.k + 1)
-        got = oracles.compare_knn(*controls.knn_control(d.points, queries, d.k),
-                                  *ref, d.points, queries,
-                                  tie_rtol=limits["tie_rtol"])
-    elif traffic["driver"] == "pair_count":
-        ref = oracles.pair_count_kdtree(d.pos, d.edge_sq)
-        ctl = controls.pair_count_control(d.pos, d.edge_sq)
-        got = {"hist_abs_error": int(np.abs(ctl - ref).sum())}
-    else:
-        raise harness.BenchError(f"no control for driver {traffic['driver']!r}")
-    return {n: {"value": got[n], "limit": limits[n]} for n in drv.COMPARED}
+    got = ctl.read(d, seed)
+    return {n: {"value": got[n], "limit": cfg["limits"][n]}
+            for n in drv.COMPARED}
 
 
 def main(argv=None) -> int:

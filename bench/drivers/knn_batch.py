@@ -63,17 +63,23 @@ class Driver:
             # the first whole call compiles what warm() does not reach
             # (the initial descent, the padding ops) and loads the rest
             self.index.query(self.pool[-1], k=self.k)
-        self.shapes = {
-            "tq": int(self.index._state.engine_tile_q),
-            "l_pad": int(self.index._state.store.host.shape[1]),
-            "d_pad": int(self.index._state.store.host.shape[2]),
-            "k": self.k,
-            "slab_itemsize": int(self.index._state.store.host.dtype.itemsize),
-            "backend": self.index.scan_backend,
-        }
+        self.shapes = self.read_shapes()
         self.log(f"[setup] knn m={self.m} k={self.k} pool={self.n_pool} "
                  f"engine={self.index.engine_name} h={self.index.height} "
                  f"shapes={self.shapes}")
+
+    def read_shapes(self) -> dict:
+        """The leaf scan's shapes, for the readers that count its work; an
+        engine whose state is laid out otherwise overrides this."""
+        store = self.index._state.store.host
+        return {
+            "tq": int(self.index._state.engine_tile_q),
+            "l_pad": int(store.shape[1]),
+            "d_pad": int(store.shape[2]),
+            "k": self.k,
+            "slab_itemsize": int(store.dtype.itemsize),
+            "backend": self.index.scan_backend,
+        }
 
     # -- the window ----------------------------------------------------------
     def call(self, i: int):

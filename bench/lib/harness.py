@@ -12,7 +12,9 @@ is found by name from ``BENCHMARK.json``:
   call of the window, and checks the window's answers against the plain
   reference (general code shared by every cell of that kind);
 - ``bench/metrics/<metric>.py``: a reader ``read(run)`` that returns the
-  metric's value, or None where it finds nothing to read.
+  metric's value, or None where it finds nothing to read;
+- ``bench/controls/<driver>.py``: the control of the driver's comparison,
+  which ``bench/control.py`` reads and no run does.
 
 Adding a cell or a metric is therefore new files and new entries only.
 """

@@ -121,8 +121,9 @@ def idle_share_in(run, spans: Sequence[str],
 
 
 def scope_seconds(run, scope: str) -> Optional[float]:
-    """Device seconds of the traced window's ``XLA Ops`` events whose name
-    or detail carries the scope path ``scope``, averaged over the devices.
+    """Device seconds of the traced window's ``XLA Ops`` events whose name,
+    detail or ``scope`` path (``trace.load_trace``) carries ``scope``,
+    averaged over the devices.
 
     An operation nested in another of the same scope (the body of a loop
     that the scope covers) is counted once: the seconds are the union of
@@ -135,7 +136,8 @@ def scope_seconds(run, scope: str) -> Optional[float]:
     for events in run.trace_data.device.values():
         hits = [(e.start_ns, e.end_ns) for e in events
                 if e.line == tr.OPS_LINE
-                and (scope in e.name or scope in e.detail)]
+                and (scope in e.name or scope in e.detail
+                     or scope in e.scope)]
         busy = tr.merge_intervals(tr._clip(hits, lo, hi))
         per_dev.append(sum(e - s for s, e in busy) * 1e-9)
     total = sum(per_dev) / max(1, len(per_dev))

@@ -13,6 +13,16 @@ The profiler writes ``<dir>/plugins/profile/<time>/<host>.xplane.pb``.
   doing (the innermost Python frame, as the profiler's Python tracer names
   it, that covers the middle of each gap).
 
+``load_trace`` also gives each device event its ``scope``: the ``tf_op``
+path (``jit(_chunk_round)/while/body/knn.merge/...``) that the compiler
+keeps in the event's metadata, where ``jax.named_scope`` scopes land.
+``ProfileData`` hands out only an event's own stats, so ``xplane_scopes``
+reads the metadata from the file's protobuf wire format itself, and
+``load_trace`` checks each path against its event: a file the reader cannot
+parse, or a path whose metadata is not its event's, raises ``ValueError``
+with the plane and line.  No reduction here reads ``scope``;
+``spans.scope_seconds`` does.
+
 The reduction is kept as code so that every benchmark computes these
 numbers the same way; it is checked on events with known answers.
 
@@ -35,6 +45,7 @@ __all__ = [
     "reduce_trace",
     "device_time",
     "short_name",
+    "xplane_scopes",
     "OPS_LINE",
     "MODULES_LINE",
 ]
@@ -53,6 +64,7 @@ class Event:
     start_ns: float
     dur_ns: float
     detail: str = ""          # long name / op path where the trace has one
+    scope: str = ""           # the metadata's tf_op path (device events)
 
     @property
     def end_ns(self) -> float:
@@ -93,16 +105,22 @@ def load_trace(log_dir: str) -> TraceData:
     """Device and host events of the newest trace under ``log_dir``."""
     from jax.profiler import ProfileData
 
-    data = ProfileData.from_file(find_xplane(log_dir))
+    path = find_xplane(log_dir)
+    data = ProfileData.from_file(path)
+    scopes = xplane_scopes(path)
     device: Dict[str, List[Event]] = {}
     host: List[Event] = []
     for plane in data.planes:
         if plane.name.startswith(DEVICE_PREFIX):
-            evs = [Event(line.name, ev.name, float(ev.start_ns),
-                         float(ev.duration_ns), _detail(ev))
-                   for line in plane.lines
-                   if line.name in (OPS_LINE, MODULES_LINE)
-                   for ev in line.events]
+            evs = []
+            for line in plane.lines:
+                if line.name not in (OPS_LINE, MODULES_LINE):
+                    continue
+                events = list(line.events)
+                tags = _line_scopes(scopes, plane.name, line.name, events)
+                evs.extend(Event(line.name, ev.name, float(ev.start_ns),
+                                 float(ev.duration_ns), _detail(ev), tag)
+                           for ev, tag in zip(events, tags))
             if evs:     # planes with no operations are not chips in use
                 device[plane.name] = evs
         elif plane.name == HOST_PLANE:
@@ -111,6 +129,173 @@ def load_trace(log_dir: str) -> TraceData:
                     host.append(Event(line.name, ev.name, float(ev.start_ns),
                                       float(ev.duration_ns)))
     return TraceData(device=device, host=host)
+
+
+def _line_scopes(scopes, plane: str, line: str, events) -> List[str]:
+    """The ``tf_op`` path of each of ``events``, the events of one line as
+    ``ProfileData`` gives them, checked one by one against what
+    ``xplane_scopes`` read: as many events, each of the same metadata name."""
+    read = scopes.get(plane, {}).get(line)
+    where = f"xplane: plane {plane!r}, line {line!r}"
+    if read is None or len(read) != len(events):
+        raise ValueError(f"{where}: {len(events)} events, the scope reader "
+                         f"found {'none' if read is None else len(read)}")
+    for i, (ev, (name, _)) in enumerate(zip(events, read)):
+        if name != ev.name:
+            raise ValueError(f"{where}, event {i}: named {ev.name!r}, the "
+                             f"scope reader's metadata {name!r}")
+    return [tag for _, tag in read]
+
+
+# ---------------------------------------------------------------------------
+# The tf_op path of each device event, from the xplane's wire format
+# ---------------------------------------------------------------------------
+SCOPE_STAT = "tf_op"    # the stat of an event's metadata that holds its path
+
+# field numbers of tsl/profiler/protobuf/xplane.proto
+_SPACE_PLANES = 1
+_PLANE_NAME, _PLANE_LINES, _PLANE_EVENT_MD, _PLANE_STAT_MD = 2, 3, 4, 5
+_LINE_NAME, _LINE_EVENTS = 2, 4
+_EVENT_MD_ID = 1
+_MAP_KEY, _MAP_VALUE = 1, 2
+_EMD_NAME, _EMD_STATS = 2, 5
+_STAT_MD_ID, _STAT_STR, _STAT_REF = 1, 5, 7
+_SMD_NAME = 2
+
+
+def _varint(buf, i: int) -> Tuple[int, int]:
+    """The varint at buf[i] and the index after it."""
+    val = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        val |= (b & 0x7F) << shift
+        if b < 0x80:
+            return val, i
+        shift += 7
+
+
+def _fields(buf, lo: int = 0, hi: Optional[int] = None):
+    """(field number, value) of each field of the message in buf[lo:hi]: an
+    int for a varint, a (start, end) slice for a length-delimited field;
+    fixed-width fields are skipped."""
+    i = lo
+    hi = len(buf) if hi is None else hi
+    while i < hi:
+        key, i = _varint(buf, i)
+        field, wire = key >> 3, key & 7
+        if wire == 0:
+            val, i = _varint(buf, i)
+            yield field, val
+        elif wire == 2:
+            n, i = _varint(buf, i)
+            if i + n > hi:
+                raise ValueError(f"field {field} at byte {i} runs past its "
+                                 f"message's end at byte {hi}")
+            yield field, (i, i + n)
+            i += n
+        elif wire == 1:
+            i += 8
+        elif wire == 5:
+            i += 4
+        else:
+            raise ValueError(f"xplane: wire type {wire} at byte {i}")
+
+
+def _text(buf, span) -> str:
+    return bytes(buf[span[0]:span[1]]).decode("utf-8", "replace")
+
+
+def _map_entries(buf, spans):
+    """(key, value slice) of each entry of a map<int64, message> field."""
+    for span in spans:
+        key, value = 0, None
+        for f, v in _fields(buf, *span):
+            if f == _MAP_KEY:
+                key = v
+            elif f == _MAP_VALUE:
+                value = v
+        if value is not None:
+            yield key, value
+
+
+def _plane_scopes(buf, plane) -> Dict[str, List[Tuple[str, str]]]:
+    parts: Dict[int, list] = {}
+    for f, v in _fields(buf, *plane):
+        if f in (_PLANE_LINES, _PLANE_EVENT_MD, _PLANE_STAT_MD):
+            parts.setdefault(f, []).append(v)
+    wanted = (OPS_LINE, MODULES_LINE)
+    ids: Dict[str, List[int]] = {}
+    for line in parts.get(_PLANE_LINES, []):
+        name, got = "", []
+        for f, v in _fields(buf, *line):
+            if f == _LINE_NAME:
+                name = _text(buf, v)
+                if name not in wanted:      # the name comes first: skip
+                    break
+            elif f == _LINE_EVENTS:
+                got.append(_event_metadata_id(buf, v))
+        if name in wanted:
+            ids[name] = got
+    needed = {i for got in ids.values() for i in got}
+    stat_names = {}
+    for key, value in _map_entries(buf, parts.get(_PLANE_STAT_MD, [])):
+        for f, v in _fields(buf, *value):
+            if f == _SMD_NAME:
+                stat_names[key] = _text(buf, v)
+    scope_ids = {k for k, name in stat_names.items() if name == SCOPE_STAT}
+    tag: Dict[int, str] = {}
+    md_name: Dict[int, str] = {}
+    for key, value in _map_entries(buf, parts.get(_PLANE_EVENT_MD, [])):
+        if key not in needed:
+            continue
+        for f, v in _fields(buf, *value):
+            if f == _EMD_NAME:
+                md_name[key] = _text(buf, v)
+            if f != _EMD_STATS:
+                continue
+            stat = dict(_fields(buf, *v))
+            if stat.get(_STAT_MD_ID) not in scope_ids:
+                continue
+            if _STAT_STR in stat:
+                tag[key] = _text(buf, stat[_STAT_STR])
+            elif _STAT_REF in stat:     # an interned string
+                tag[key] = stat_names.get(stat[_STAT_REF], "")
+    return {name: [(md_name.get(i, ""), tag.get(i, "")) for i in got]
+            for name, got in ids.items()}
+
+
+def _event_metadata_id(buf, span) -> int:
+    """An event's ``metadata_id``: its first field as the profiler writes
+    it, else wherever it stands."""
+    if span[1] > span[0] and buf[span[0]] == (_EVENT_MD_ID << 3):
+        return _varint(buf, span[0] + 1)[0]
+    return next((x for g, x in _fields(buf, *span) if g == _EVENT_MD_ID), 0)
+
+
+def xplane_scopes(path: str) -> Dict[str, Dict[str, List[Tuple[str, str]]]]:
+    """For each device plane of the xplane file at ``path``, and each of its
+    ``XLA Ops`` and ``XLA Modules`` lines, the metadata name and the
+    ``tf_op`` path of every event in the file's order ("" where its metadata
+    has none).  Other planes, the host's among them, are skipped unread.
+    Raises ``ValueError``, naming the plane, where the file cannot be read."""
+    with open(path, "rb") as f:
+        buf = memoryview(f.read())
+    try:
+        planes = [v for f, v in _fields(buf) if f == _SPACE_PLANES]
+    except (ValueError, IndexError) as e:
+        raise ValueError(f"xplane {path}: {e}") from e
+    out: Dict[str, Dict[str, List[Tuple[str, str]]]] = {}
+    for plane in planes:
+        name = "?"
+        try:
+            name = next((_text(buf, v) for g, v in _fields(buf, *plane)
+                         if g == _PLANE_NAME), "")
+            if name.startswith(DEVICE_PREFIX):
+                out[name] = _plane_scopes(buf, plane)
+        except (ValueError, IndexError) as e:
+            raise ValueError(f"xplane {path}: plane {name!r}: {e}") from e
+    return out
 
 
 def merge_intervals(spans: Iterable[Tuple[float, float]]) -> List[Tuple[float, float]]:

@@ -111,7 +111,34 @@ def test_every_piece_has_its_file(bench):
         assert os.path.isfile(os.path.join(b, "metrics", m["name"] + ".py"))
 
 
+def test_every_driver_has_its_control_and_faults(bench):
+    """Each driver that a cell's traffic names brings its control,
+    ``bench/controls/<driver>.py`` with ``read(driver, seed)``, and its
+    planted faults, ``tests/bench/faults/<driver>.py`` with a non-empty
+    ``FAULTS``: the tests find both by the driver's name."""
+    import bench_cells
+    from bench.lib import harness
+
+    missing = []
+    for w in bench["workloads"]:
+        driver = bench_cells.driver_of(w, ROOT)
+        ctl = os.path.join(ROOT, "bench", "controls", f"{driver}.py")
+        if not os.path.isfile(ctl) or not callable(
+                getattr(harness.load_module(ctl), "read", None)):
+            missing.append(f"{w['name']}: bench/controls/{driver}.py read()")
+        if not bench_cells.faults_of(driver):
+            missing.append(f"{w['name']}: tests/bench/faults/{driver}.py "
+                           "FAULTS")
+    assert not missing, missing
+
+
 def test_every_cell_reports_what_it_must(bench):
+    """Every cell reports ``setup_s``, one more end-to-end metric and one
+    per-layer metric.  An end-to-end metric with a ``workloads`` list is
+    reported only by the cells it lists: a cell joins such a metric by
+    appending its own name to that list, the one touch of an existing entry
+    that a PR adding a cell makes.  Its per-layer metrics are new entries
+    with files of their own."""
     e2e = {m["name"]: m for m in bench["end_to_end"]}
     for m in bench["per_layer"]:
         assert m["moves"] in e2e

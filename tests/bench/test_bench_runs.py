@@ -1,8 +1,14 @@
 """Every cell rehearsed end to end on the CPU at tiny size (Pallas in
 interpret mode), the refusals of the command, the controls, and faults
-planted under the timed path that must turn ``correct`` false."""
+planted under the timed path that must turn ``correct`` false.
+
+A one-chip cell runs in this process.  A cell of more than one chip runs
+all of that in one process of its own that sees as many CPU devices
+(``on_devices.py``); each case here judges its part.  The faults of a cell
+are those of its driver (``tests/bench/faults/<driver>.py``)."""
 
 import argparse
+import functools
 import json
 import os
 import shutil
@@ -12,11 +18,18 @@ import sys
 import numpy as np
 import pytest
 
+import bench_cells
 from bench import control
 from bench.lib import harness
 
 ROOT = harness.ROOT
-CELLS = [w["name"] for w in harness.load_benchmark()["workloads"]]
+CELL = bench_cells.cells()
+CELLS = list(CELL)
+REHEARSAL_SEED = 4294967311
+FAULT_SEED = 11
+CONTROL_SEEDS = (1, 2, 3)
+FAULTS = {w: {f.__name__: f for f in bench_cells.faults_of(
+    bench_cells.driver_of(CELL[w]))} for w in CELLS}
 
 
 def _args(workload, seed=3_000_000_019, seconds=0.5, trace=0):
@@ -49,17 +62,41 @@ def _assert_well_formed(result, workload, trace):
         assert set(c) == {"value", "limit"}
 
 
+@functools.lru_cache(maxsize=None)
+def _on_devices(workload):
+    """The rehearsals, controls and faults of a cell of several chips, from
+    one process that sees that many CPU devices."""
+    p = subprocess.run(
+        [sys.executable, os.path.join(bench_cells.HERE, "on_devices.py"),
+         "--workload", workload, "--devices", str(CELL[workload]["chips"]),
+         "--seed", str(REHEARSAL_SEED), "--fault-seed", str(FAULT_SEED),
+         "--control-seeds", ",".join(map(str, CONTROL_SEEDS))],
+        cwd=ROOT, env=bench_cells.system_env(), capture_output=True,
+        text=True, timeout=1200)
+    assert p.returncode == 0, p.stderr[-4000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+def _several_chips(workload) -> bool:
+    return CELL[workload]["chips"] > 1
+
+
 @pytest.mark.parametrize("trace", [0, 1])
 @pytest.mark.parametrize("workload", CELLS)
 def test_cell_rehearses_and_prints_a_well_formed_last_line(
         workload, trace, capsys):
-    rc = harness.main(["--workload", workload, "--seed", "4294967311",
-                       "--seconds", "0.5", "--trace", str(trace),
-                       "--rehearse"])
-    out, err = capsys.readouterr()
-    assert rc == 0
+    if _several_chips(workload):
+        got = _on_devices(workload)["rehearsals"][str(trace)]
+        rc, out, err = got["rc"], got["out"], got["err"]
+    else:
+        rc = harness.main(["--workload", workload, "--seed",
+                           str(REHEARSAL_SEED), "--seconds", "0.5",
+                           "--trace", str(trace), "--rehearse"])
+        out, err = capsys.readouterr()
+    assert rc == 0, err[-4000:]
     result = json.loads(out.strip().splitlines()[-1])
     _assert_well_formed(result, workload, trace)
+    assert result["device"]["count"] == CELL[workload]["chips"]
     assert result["correct"] is True
     # the compared numbers close standard error, each beside its limit
     tail = err.strip().splitlines()[-len(result["checks"]):]
@@ -96,119 +133,26 @@ def test_command_refuses_a_directory_without_the_system(tmp_path):
 
 @pytest.mark.parametrize("workload", CELLS)
 def test_control_comes_out_not_correct(workload):
-    for seed in (1, 2, 3):
-        got = control.read_control(workload, seed, rehearse=True)
+    for seed in CONTROL_SEEDS:
+        if _several_chips(workload):
+            got = _on_devices(workload)["controls"][str(seed)]
+        else:
+            got = control.read_control(workload, seed, rehearse=True)
         assert any(c["value"] > c["limit"] for c in got.values()), got
 
 
 # ---------------------------------------------------------------------------
-# Faults planted under the timed path
+# Faults planted under the timed path (tests/bench/faults/<driver>.py)
 # ---------------------------------------------------------------------------
-def _knn_answer_altered(mp):
-    import repro.core.lazysearch as ls
-
-    orig = ls.finalize_candidates
-
-    def bad(tree, queries, gi):
-        d, i = orig(tree, queries, gi)
-        i = i.copy()
-        i[:, -1] = (i[:, -1] + 1) % tree.n
-        return d, i
-
-    mp.setattr(ls, "finalize_candidates", bad)
-
-
-def _knn_half_batch(mp):
-    from repro.api import KNNIndex
-
-    orig = KNNIndex.query
-
-    def half(self, queries, k=None):
-        res = orig(self, queries[: len(queries) // 2], k)
-        m = len(queries)
-        d = np.full((m, res.k), np.inf, np.float32)
-        i = np.full((m, res.k), -1, np.int64)
-        d[: len(res.dists)], i[: len(res.idx)] = res.dists, res.idx
-        return type(res)(dists=d, idx=i, stats=res.stats, engine=res.engine,
-                         k=res.k)
-
-    mp.setattr(KNNIndex, "query", half)
-
-
-def _knn_state_unchanged(mp):
-    import jax.numpy as jnp
-
-    import repro.core.chunked_jit as cj
-
-    orig = cj._chunk_round
-
-    def unchanged(node, fromc, leaf, knn_d, knn_i, *a, **kw):
-        out = orig(node, fromc, leaf, knn_d, knn_i, *a, **kw)
-        # the round advances the traversal but hands back its initial
-        # neighbour state, as if the scan and merge never happened
-        return (*out[:3], jnp.full_like(out[3], jnp.inf),
-                jnp.full_like(out[4], -1), out[5])
-
-    unchanged._cache_size = orig._cache_size    # the program's audit
-    mp.setattr(cj, "_chunk_round", unchanged)
-
-
-def _pc_answer_altered(mp):
-    from repro.core.dualtree import DualTree
-
-    orig = DualTree.pair_count
-
-    def bad(self, edges):
-        hist, stats = orig(self, edges)
-        hist = hist.copy()
-        hist[len(hist) // 2] += 2
-        return hist, stats
-
-    mp.setattr(DualTree, "pair_count", bad)
-
-
-def _pc_half_batch(mp):
-    import repro.core.dualtree as dt
-
-    orig = dt._pair_hist_kernel
-
-    def half(*a):
-        h = orig(*a)
-        # the second half of every leaf-pair batch is left out
-        return h.at[h.shape[0] // 2:].set(0)
-
-    half._cache_size = orig._cache_size         # the program's audit
-    mp.setattr(dt, "_pair_hist_kernel", half)
-
-
-def _pc_state_unchanged(mp):
-    import jax.numpy as jnp
-
-    import repro.core.dualtree as dt
-
-    orig = dt._pair_hist_kernel
-
-    def nothing(*a):
-        return jnp.zeros_like(orig(*a))
-
-    nothing._cache_size = orig._cache_size      # the program's audit
-    mp.setattr(dt, "_pair_hist_kernel", nothing)
-
-
-FAULTS = {
-    "photo10m-knn-batch": [_knn_answer_altered, _knn_half_batch,
-                           _knn_state_unchanged],
-    "lattice131k-2pcf": [_pc_answer_altered, _pc_half_batch,
-                         _pc_state_unchanged],
-}
-
-
 @pytest.mark.parametrize("workload,fault", [
-    (w, f) for w in CELLS for f in FAULTS[w]],
-    ids=lambda x: getattr(x, "__name__", x))
+    (w, f) for w in CELLS for f in FAULTS[w]])
 def test_planted_fault_turns_correct_false(workload, fault, monkeypatch):
-    fault(monkeypatch)
-    result = _run(workload, seed=11)
+    if _several_chips(workload):
+        result = _on_devices(workload)["faults"][fault]
+        assert "error" not in result, result
+    else:
+        FAULTS[workload][fault](monkeypatch)
+        result = _run(workload, seed=FAULT_SEED)
     assert result["correct"] is False, result["checks"]
     # the line stays strict JSON however far off a reading is
     json.loads(json.dumps(result), parse_constant=pytest.fail)
